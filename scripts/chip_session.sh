@@ -6,13 +6,13 @@
 # bench's own retry ladder handles intra-run blips; this loop handles
 # multi-hour outages).
 #
-# The bench's outage envelope (TPU_BFS_BENCH_BUDGET_S, default 1200 s —
-# sized inside the driver's observed ~30-40 min kill window) makes each
-# attempt terminate cleanly when the chip never comes up: the JSON line is
-# either a stale echo of the last durable-log number ("stale": true) or
-# value=null when the log has nothing. rc alone no longer distinguishes
-# success, so every stage's JSON is checked for a FRESH non-null value
-# (scripts/has_value.py rejects stale echoes, keeping the stage retrying).
+# The bench's outage envelope (TPU_BFS_BENCH_BUDGET_S, default 1200 s)
+# makes each attempt terminate with one JSON line; a run that measured
+# nothing prints value=null and exits nonzero. Every stage's JSON is
+# checked for a non-null value (scripts/has_value.py).
+#
+# Superseded as the first chip step by chip_smoke.py (PR 21); kept for the
+# sweep arms below until the benchmark PR defines the cells.
 #
 # Since round 4 the bench DEFAULTS are the measured-best configuration
 # (8192 lanes + level-adaptive push), so the headline "flagship" stage

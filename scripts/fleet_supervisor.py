@@ -27,6 +27,13 @@ final JSON summary line (restarts, requeues, served) for stage drivers.
 Exactly-once emission: the internal-id map is the gate — a dying
 replica's late answer and the sibling's requeued answer can both
 arrive, but only the first one out of the map is emitted.
+
+One chip per replica: a TPU chip serves one process at a time, so each
+replica is pinned to its own chip (``TPU_VISIBLE_CHIPS`` plus one-chip
+process bounds) and the supervisor refuses more replicas than the chips
+it may use. A replacement reuses its predecessor's chip only after the
+predecessor has exited. Replicas forced off the TPU (``JAX_PLATFORMS``
+without ``tpu``, as in the tests) take no chip and are not limited.
 """
 
 from __future__ import annotations
@@ -42,7 +49,43 @@ import threading
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from warm_handoff import READY_MARKER, pid_alive  # noqa: E402
+from warm_handoff import (  # noqa: E402
+    ALL_CHIPS,
+    READY_MARKER,
+    chips_of,
+    host_chip_count,
+    pid_alive,
+)
+
+
+def replica_chips(n: int, env=None) -> list:
+    """One TPU chip id per replica, or ``[None] * n`` when the replicas
+    run off the TPU. Raises when there are fewer usable chips than
+    replicas."""
+    chips = chips_of(dict(os.environ if env is None else env))
+    if chips == frozenset():
+        return [None] * n
+    usable = (list(range(host_chip_count())) if chips is ALL_CHIPS
+              else sorted(chips))
+    if n > len(usable):
+        raise ValueError(
+            f"{n} replicas need {n} TPU chips, one each; this process may "
+            f"use {len(usable)} ({usable}) — a chip serves one process")
+    return usable[:n]
+
+
+def pinned_env(chip) -> dict:
+    """The environment of a replica pinned to ``chip`` (None: unpinned)."""
+    env = dict(os.environ)
+    if chip is not None:
+        env.update(
+            TPU_VISIBLE_CHIPS=str(chip),
+            TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+            TPU_PROCESS_BOUNDS="1,1,1",
+            # A distinct slice-builder port per process on the host.
+            TPU_PROCESS_PORT=str(8476 + int(chip)),
+        )
+    return env
 
 
 def _log(msg: str) -> None:
@@ -52,8 +95,10 @@ def _log(msg: str) -> None:
 class Replica:
     """One supervised server process: spawned, READY-gated, watched."""
 
-    def __init__(self, idx: int, argv, *, on_response, on_exit, log=_log):
+    def __init__(self, idx: int, argv, *, on_response, on_exit, log=_log,
+                 chip=None):
         self.idx = idx
+        self.chip = chip
         self.argv = list(argv)
         self._log = log
         self._on_response = on_response
@@ -64,9 +109,10 @@ class Replica:
         self._lock = threading.Lock()
         self.proc = subprocess.Popen(
             self.argv, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.PIPE, text=True,
+            stderr=subprocess.PIPE, text=True, env=pinned_env(chip),
         )
-        log(f"replica {idx}: spawned pid {self.proc.pid}")
+        where = "" if chip is None else f" on TPU chip {chip}"
+        log(f"replica {idx}: spawned pid {self.proc.pid}{where}")
         threading.Thread(target=self._watch_stdout,
                          name=f"fleet-out-{idx}", daemon=True).start()
         threading.Thread(target=self._watch_stderr,
@@ -147,6 +193,7 @@ class FleetSupervisor:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
         self.server_argv = list(server_argv)
         self.n = replicas
+        self.chips = replica_chips(replicas)
         self.ready_timeout = ready_timeout
         self.term_wait = term_wait
         self.heartbeat_timeout = heartbeat_timeout
@@ -208,7 +255,8 @@ class FleetSupervisor:
 
     def _spawn(self, idx: int) -> Replica:
         rep = Replica(idx, self.server_argv, on_response=self._on_response,
-                      on_exit=self._on_exit, log=self._log)
+                      on_exit=self._on_exit, log=self._log,
+                      chip=self.chips[idx])
         with self._lock:
             self._replicas.append(rep)
         return rep
@@ -299,6 +347,14 @@ class FleetSupervisor:
             # set only once its own READY line lands (_pick gates on
             # ready), so a crash-looping binary cannot take traffic.
             self._log(f"replica {rep.idx}: spawning replacement")
+            if rep.chip is not None:
+                # The replacement takes the same chip: wait until the
+                # predecessor has let go of it.
+                try:
+                    rep.proc.wait(timeout=max(self.term_wait, 0.1))
+                except subprocess.TimeoutExpired:
+                    rep.proc.kill()
+                    rep.proc.wait()
             self.restarts += 1
             self._spawn(rep.idx)
 
@@ -402,11 +458,16 @@ def main(argv=None) -> int:
     if not server:
         ap.error("no server argv given (append: -- <server argv...>)")
 
-    fleet = FleetSupervisor(
-        server, replicas=args.replicas, ready_timeout=args.ready_timeout,
-        term_wait=args.term_wait, heartbeat_timeout=args.heartbeat_timeout,
-        restart=not args.no_restart,
-    ).start()
+    try:
+        fleet = FleetSupervisor(
+            server, replicas=args.replicas, ready_timeout=args.ready_timeout,
+            term_wait=args.term_wait,
+            heartbeat_timeout=args.heartbeat_timeout,
+            restart=not args.no_restart,
+        )
+    except ValueError as exc:
+        ap.error(str(exc))
+    fleet.start()
     try:
         for line in sys.stdin:
             line = line.strip()

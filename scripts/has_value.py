@@ -2,10 +2,9 @@
 
 The one shared gate for bench output (scripts/chip_session.sh — its sole
 caller since the adaptive follow-on stage was folded into the session's
-flagship-noadaptive arm): the bench's outage envelope exits 0 with a
-value=null JSON when the chip never comes up, so rc alone cannot
-distinguish a landed measurement — keeping the contract in one place
-stops orchestration scripts from drifting.
+flagship-noadaptive arm): a landed measurement is a last JSON line with a
+non-null value — keeping the contract in one place stops orchestration
+scripts from drifting.
 """
 
 import json
@@ -17,9 +16,8 @@ def main(path: str) -> int:
         with open(path) as f:
             lines = [l for l in f if l.strip().startswith("{")]
         entry = json.loads(lines[-1]) if lines else {}
-        # A stale echo (round 5: the envelope replays the last durable-log
-        # number when a run is lost) is NOT a landed measurement — stages
-        # must keep retrying until a fresh value lands.
+        # A line marked stale (older bench versions echoed the last
+        # durable-log number for a lost run) is NOT a landed measurement.
         return 0 if entry.get("value") is not None and not entry.get("stale") else 1
     except Exception:  # noqa: BLE001 — any unreadable file is "no value"
         return 1

@@ -15,6 +15,14 @@ Usage::
         [--ready-timeout S] [--term-wait S] -- <successor argv...>
 
 ``--old-pid 0`` skips the SIGTERM (first bring-up: just gate on READY).
+
+A TPU chip belongs to one process at a time: a successor started on the
+chip the old server still holds would fail or hang inside libtpu. So the
+driver refuses, before starting anything, when the successor could open
+a chip the old server holds (``TPU_VISIBLE_CHIPS`` of both; unset means
+every chip). Pin the successor to a free chip, or stop the old server
+first. Processes forced off the TPU (``JAX_PLATFORMS`` without ``tpu``)
+hold no chip.
 The driver's stdin/stdout pass through to the successor, so a fleet
 manager (or the preheat smoke) can pipe traffic straight into the new
 process. Prints one JSON line (value = seconds to ready) on success.
@@ -22,6 +30,7 @@ process. Prints one JSON line (value = seconds to ready) on success.
 
 import argparse
 import errno
+import glob
 import json
 import os
 import signal
@@ -55,6 +64,48 @@ def pid_alive(pid: int) -> bool:
     return True
 
 
+ALL_CHIPS = None  # a process with no TPU_VISIBLE_CHIPS opens every chip
+
+
+def chips_of(env: dict):
+    """The TPU chips a process with environment ``env`` may open: an
+    empty set when it is forced off the TPU, ALL_CHIPS when unpinned,
+    else the chip ids of TPU_VISIBLE_CHIPS."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.lower().split(","):
+        return frozenset()
+    visible = env.get("TPU_VISIBLE_CHIPS")
+    if visible is None:
+        return ALL_CHIPS
+    return frozenset(int(c) for c in visible.split(",") if c.strip())
+
+
+def chips_overlap(a, b) -> bool:
+    if a == frozenset() or b == frozenset():
+        return False
+    return a is ALL_CHIPS or b is ALL_CHIPS or bool(a & b)
+
+
+def process_env(pid: int) -> dict | None:
+    """The environment a live process was started with (None when it
+    cannot be read)."""
+    try:
+        with open(f"/proc/{pid}/environ", "rb") as f:
+            raw = f.read()
+    except OSError:
+        return None
+    pairs = (item.partition(b"=") for item in raw.split(b"\0") if item)
+    return {k.decode(errors="replace"): v.decode(errors="replace")
+            for k, _, v in pairs}
+
+
+def host_chip_count() -> int:
+    """TPU chips on this host, counted from their device files — never
+    through JAX, which would take a chip in this process."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*"))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="drain the old server only after the new one is ready"
@@ -80,6 +131,18 @@ def main(argv=None) -> int:
         log(f"old pid {args.old_pid} is not alive; treating as first "
             f"bring-up")
         args.old_pid = 0
+    if args.old_pid:
+        old_env = process_env(args.old_pid)
+        old_chips = ALL_CHIPS if old_env is None else chips_of(old_env)
+        if chips_overlap(old_chips, chips_of(dict(os.environ))):
+            log(f"refusing: the successor could open a TPU chip that old "
+                f"server pid {args.old_pid} holds (old TPU_VISIBLE_CHIPS="
+                f"{None if old_env is None else old_env.get('TPU_VISIBLE_CHIPS')}"
+                f", successor TPU_VISIBLE_CHIPS="
+                f"{os.environ.get('TPU_VISIBLE_CHIPS')}); a chip serves one "
+                f"process — pin the successor to a free chip or stop the "
+                f"old server first")
+            return 2
 
     t0 = time.perf_counter()
     log(f"starting successor: {' '.join(succ)}")

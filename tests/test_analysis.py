@@ -20,7 +20,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from tpu_bfs.analysis import Finding, apply_baseline, load_baseline
 from tpu_bfs.analysis import dtypes, uniformity
 from tpu_bfs.analysis.locks import find_cycles, lint_sources, lint_tree, repo_root
-from tpu_bfs.parallel.compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(scope="module")
@@ -320,7 +320,7 @@ def test_planner_programs_verify_uniform():
 
 
 def test_dtype_pass_flags_f64():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(np.float64(1.0))
     findings = dtypes.check_jaxpr("seeded-f64", closed)
     assert findings and findings[0].pass_name == "dtype"
@@ -333,7 +333,7 @@ def test_hlo_wide_dtype_scan_flags_f64():
     instruction name side would be a permanent no-op)."""
     from tpu_bfs.analysis.hlo import wide_dtype_lines
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         hlo = (
             jax.jit(lambda x: x * 2.0)
             .lower(np.float64(1.0))
@@ -347,7 +347,7 @@ def test_hlo_wide_dtype_scan_flags_f64():
 
 
 def test_dtype_pass_flags_i64_widening():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: jnp.cumsum(x.astype(jnp.int64))
         )(np.arange(4, dtype=np.int32))
@@ -379,7 +379,7 @@ def test_dtype_pass_sees_inside_pallas_kernel():
         o_ref[:] = x_ref[:] * 2.0
 
     x = np.ones((8, 8), np.float32)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         seeded = jax.make_jaxpr(call(bad))(x)
         clean = jax.make_jaxpr(call(good))(x)
     findings = dtypes.check_jaxpr("seeded-kernel-f64", seeded)

@@ -198,7 +198,8 @@ def test_console_entry_points_resolve():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "pyproject.toml"), "rb") as f:
         scripts = tomllib.load(f)["project"]["scripts"]
-    assert set(scripts) == {"tpu-bfs", "tpu-bfs-graph500"}
+    assert set(scripts) == {"tpu-bfs", "tpu-bfs-graph500", "tpu-bfs-serve",
+                            "tpu-bfs-analyze"}
     for target in scripts.values():
         mod, fn = target.split(":")
         func = getattr(importlib.import_module(mod), fn)

@@ -23,7 +23,7 @@ from tpu_bfs.parallel.collectives import (
     sparse_exchange_or,
     unpack_bits,
 )
-from tpu_bfs.parallel.compat import shard_map
+from jax import shard_map
 from tpu_bfs.parallel.dist_bfs import make_mesh
 
 # Lengths straddling word boundaries: 1 (single bit), 31/33 (one off a

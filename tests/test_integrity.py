@@ -86,7 +86,7 @@ def test_checksummed_ring_or_semantics_and_byte_model():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from tpu_bfs.integrity.wire import checksummed_ring_or
-    from tpu_bfs.parallel.compat import shard_map
+    from jax import shard_map
     from tpu_bfs.utils.wirecheck import check_wire_checksum
 
     p = 8
@@ -102,6 +102,7 @@ def test_checksummed_ring_or_semantics_and_byte_model():
 
         fn = jax.jit(shard_map(
             body, mesh=mesh, in_specs=P("x"), out_specs=(P("x"), P("x")),
+            check_vma=False,
         ))
         out, bad = fn(jnp.asarray(chunks))
         assert np.array_equal(
@@ -175,7 +176,9 @@ def test_structural_bfs_clean_and_corrupt():
 def test_structural_sssp_relaxation_property():
     from scipy.sparse import csgraph
 
-    g = rmat_graph(7, 8, seed=31, weights=5)
+    # dedup: scipy sums duplicate CSR slots, so a multigraph's parallel
+    # edges would reach dijkstra with doubled weights (a wrong oracle).
+    g = rmat_graph(7, 8, seed=31, weights=5, dedup=True)
     aud = StructuralAuditor(g)
     d = csgraph.dijkstra(g.to_scipy(weighted=True), indices=0)
     dist = np.where(np.isinf(d), INF_DIST, d).astype(np.int32)

@@ -133,7 +133,7 @@ def test_roofline_records_active_tiles(g_rmat, eng_gated):
     from tpu_bfs.utils.roofline import roofline_hybrid
 
     srcs = _sources(g_rmat, 64)
-    rep = roofline_hybrid(eng_gated, srcs)
+    rep = roofline_hybrid(eng_gated, srcs, peak_gbs=819.0)
     assert rep["pull_gate"] is True
     ats = [la["active_tiles"] for la in rep["levels"]]
     assert all(a is not None and a >= 0 for a in ats)

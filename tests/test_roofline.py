@@ -18,7 +18,16 @@ import pytest
 from tpu_bfs.algorithms.msbfs_hybrid import HybridMsBfsEngine
 from tpu_bfs.graph.generate import rmat_graph
 from tpu_bfs.reference import bfs_scipy
-from tpu_bfs.utils.roofline import phase_bytes, phase_fns, roofline_hybrid
+from tpu_bfs.utils.roofline import (
+    device_peaks,
+    phase_bytes,
+    phase_fns,
+    roofline_hybrid,
+)
+
+# These run on the CPU, which has no published peak: the byte model is
+# checked against the v5e figure, given explicitly.
+PEAK_GBS = device_peaks("TPU v5 lite")["hbm_gbs"]
 
 
 @pytest.fixture(scope="module")
@@ -46,7 +55,8 @@ def _sources(g, n, seed=7):
 def test_report_structure_and_level_parity(small_graph, engine):
     sources = _sources(small_graph, 64)
     res = engine.run(sources)
-    report = roofline_hybrid(engine, sources, measured_gteps=1.0)
+    report = roofline_hybrid(engine, sources, measured_gteps=1.0,
+                             peak_gbs=PEAK_GBS)
     # stepping runs one body per level incl. the final empty-frontier one.
     assert report["num_levels"] in (res.num_levels, res.num_levels + 1)
     assert report["binding_term"] in report["phase_share"]
@@ -93,7 +103,7 @@ def test_stepping_does_not_perturb_distances(small_graph, adaptive_engine):
     sampled lanes against the SciPy oracle — the instrument must leave the
     engine reusable and the traversal correct."""
     sources = _sources(small_graph, 64)
-    report = roofline_hybrid(adaptive_engine, sources)
+    report = roofline_hybrid(adaptive_engine, sources, peak_gbs=PEAK_GBS)
     assert report["num_levels"] >= 1
     res = adaptive_engine.run(sources)
     for i in (0, 31, 63):
@@ -106,7 +116,7 @@ def test_adaptive_attribution_matches_gate(small_graph, adaptive_engine):
     """Levels labeled 'push' must be exactly the light levels the fused
     loop's gate takes: frontier rows <= row_cap and no ineligible row."""
     sources = _sources(small_graph, 64)
-    report = roofline_hybrid(adaptive_engine, sources)
+    report = roofline_hybrid(adaptive_engine, sources, peak_gbs=PEAK_GBS)
     row_cap = adaptive_engine.adaptive_push[0]
     saw_push = False
     for la in report["levels"]:
@@ -141,7 +151,8 @@ def test_pallas_tier_attribution(small_graph):
     eng = HybridMsBfsEngine(
         small_graph, lanes=64, num_planes=4, expand_impl="pallas"
     )
-    report = roofline_hybrid(eng, sources, measured_gteps=1.0)
+    report = roofline_hybrid(eng, sources, measured_gteps=1.0,
+                             peak_gbs=PEAK_GBS)
     assert report["expand_impl"] == "pallas"
     kb = report["expand_kernel_bytes"]
     assert kb["level_total"] == sum(
@@ -158,7 +169,8 @@ def test_pallas_tier_attribution(small_graph):
     # explicitly empty for it — bench keys can never lie about the tier).
     xla = HybridMsBfsEngine(small_graph, lanes=64, num_planes=4)
     assert pallas_expand_bytes(xla) == {}
-    assert "expand_kernel_bytes" not in roofline_hybrid(xla, sources)
+    assert "expand_kernel_bytes" not in roofline_hybrid(
+        xla, sources, peak_gbs=PEAK_GBS)
     # Gated-out tiles cost only their output writes: the all-gated model
     # is strictly below the full one.
     full = sum(pallas_expand_bytes(eng).values())
@@ -182,3 +194,14 @@ def test_distributed_ms_exchange_entry(small_graph):
         eng._gather_p, eng._gather_rows_loc, eng.w
     )
     assert pb["exchange"] > 0
+
+
+def test_peaks_keyed_by_device_kind():
+    import jax
+
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e == {"hbm_gbs": 819.0, "bf16_tflops": 197.0}
+    # The CPU (and any device not in the table) has no peak: an error,
+    # never a default.
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks(jax.devices()[0].device_kind)

@@ -175,3 +175,24 @@ def test_fuzz_line_stream_survives(_serve):
     assert all(str(r["id"]).startswith("ok-") for r in ok)
     assert all("bad request" in r["error"] or "out of range" in r["error"]
                for r in bad)
+
+
+def test_server_process_exits_zero_after_eof():
+    """``python -m tpu_bfs.serve`` drains at EOF and exits 0: the statsz
+    thread is joined, not left waking at interpreter exit (that aborted
+    every clean shutdown with rc 134, read by the fleet and the handoff
+    driver as a crashed replica)."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-m", "tpu_bfs.serve", "random:n=64,m=256,seed=1",
+         "--lanes", "32", "--ladder", "off"],
+        input='{"id": 1, "source": 0}\n', cwd=repo, capture_output=True,
+        text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert json.loads(proc.stdout.splitlines()[0])["status"] == "ok"

@@ -264,3 +264,63 @@ def test_fleet_client_id_collisions_across_replicas():
         fleet.close()
     assert [r["id"] for r in responses] == ["same", "same"]
     assert sorted(r["source"] for r in responses) == [1, 2]
+
+
+# --- one process per chip ----------------------------------------------------
+
+
+def test_chip_sets_from_env():
+    chips_of = warm_handoff.chips_of
+    assert chips_of({"JAX_PLATFORMS": "cpu"}) == frozenset()
+    assert chips_of({}) is warm_handoff.ALL_CHIPS
+    assert chips_of({"TPU_VISIBLE_CHIPS": "1,3"}) == {1, 3}
+    overlap = warm_handoff.chips_overlap
+    assert overlap(warm_handoff.ALL_CHIPS, frozenset({2}))
+    assert overlap(frozenset({1, 3}), frozenset({3}))
+    assert not overlap(frozenset({0}), frozenset({1}))
+    assert not overlap(frozenset(), warm_handoff.ALL_CHIPS)
+
+
+@pytest.mark.parametrize("succ_chips,refused", [("0", True), ("1", False)])
+def test_handoff_refuses_successor_on_held_chip(monkeypatch, succ_chips,
+                                                refused):
+    """A successor that could open the chip the old server holds is
+    refused up front (rc 2, nothing started, old server untouched); one
+    pinned to another chip proceeds to the READY gate."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    old = subprocess.Popen(
+        [sys.executable, "-u", "-c", "import time; print('up', flush=True);"
+         " time.sleep(600)"],
+        stdout=subprocess.PIPE, text=True,
+        env=dict(env, TPU_VISIBLE_CHIPS="0"),
+    )
+    try:
+        assert old.stdout.readline().strip() == "up"
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", succ_chips)
+        rc = warm_handoff.main([
+            "--old-pid", str(old.pid), "--ready-timeout", "30",
+            "--", sys.executable, "-c", "import sys; sys.exit(3)",
+        ])
+        # Refused: 2 before the successor runs; allowed: the successor
+        # ran and died before READY (1).
+        assert rc == (2 if refused else 1)
+        assert old.poll() is None and warm_handoff.pid_alive(old.pid)
+    finally:
+        old.kill()
+        old.wait()
+
+
+def test_fleet_pins_one_chip_per_replica(monkeypatch):
+    import fleet_supervisor as fs
+
+    monkeypatch.setattr(fs, "host_chip_count", lambda: 2)
+    assert fs.replica_chips(2, env={}) == [0, 1]
+    assert fs.replica_chips(1, env={"TPU_VISIBLE_CHIPS": "3"}) == [3]
+    assert fs.replica_chips(3, env={"JAX_PLATFORMS": "cpu"}) == [None] * 3
+    with pytest.raises(ValueError, match="3 replicas need 3 TPU chips"):
+        fs.replica_chips(3, env={})
+    pinned = fs.pinned_env(1)
+    assert pinned["TPU_VISIBLE_CHIPS"] == "1"
+    assert pinned["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert fs.pinned_env(None) == dict(os.environ)

@@ -78,6 +78,7 @@ from tpu_bfs.algorithms._packed_common import (
     tpu_padded_words,
 )
 from tpu_bfs.ops.tile_spmm import AW, TILE, tile_spmm
+from tpu_bfs.ops.ell_expand import resolve_interpret
 
 W = 128
 LANES = 32 * W
@@ -443,8 +444,7 @@ class HybridMsBfsEngine(PackedRunProtocol, PullGateHost,
         # auto sizing can ever select): a non-pow2 cap would otherwise make
         # auto_planes' full-width check unsatisfiable in EVERY auto branch.
         max_lanes = floor_lanes(max_lanes)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
         self.hg = (
             build_hybrid(
                 graph, kcap=kcap, tile_thr=tile_thr, a_budget_bytes=a_budget_bytes

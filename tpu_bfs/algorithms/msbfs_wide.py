@@ -47,6 +47,7 @@ import numpy as np
 
 from tpu_bfs.graph.csr import Graph
 from tpu_bfs.graph.ell import EllGraph, build_ell, pad_gate_blocks
+from tpu_bfs.ops.ell_expand import resolve_interpret
 from tpu_bfs.algorithms._packed_common import (
     AotProgramProtocol,
     ExpandSpec,
@@ -184,10 +185,7 @@ class WidePackedMsBfsEngine(PackedRunProtocol, PullGateHost,
             raise ValueError(
                 "overlay does not compose with pull_gate or adaptive_push"
             )
-        if interpret is None:
-            # Same resolution as the hybrid engine's tile kernel: emulate
-            # the Pallas tier off-TPU so CPU tests drive the real kernel.
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
         self.expand_impl = expand_impl
         self._interpret = bool(interpret)
         if pull_gate and adaptive_push is not None:

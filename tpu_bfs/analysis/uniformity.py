@@ -405,10 +405,13 @@ def analyze_jaxpr(name: str, closed) -> UniformityReport:
         full = frozenset(sm.params["mesh"].axis_names)
         body = _inner_jaxpr(sm.params["jaxpr"])
         taint = _Taint(full)
-        for var, names in zip(body.invars, sm.params["in_names"]):
-            sharded: set = set()
-            for axes in names.values():
-                sharded.update(axes)
+        for var, spec in zip(body.invars, sm.params["in_specs"]):
+            # Mesh axes named anywhere in the input's PartitionSpec.
+            sharded = {
+                ax
+                for entry in spec if entry is not None
+                for ax in ((entry,) if isinstance(entry, str) else entry)
+            }
             taint.write(var, full - sharded)
         _analyze_body(body, taint, report, seen)
     return report

@@ -257,7 +257,7 @@ def _make_ms_engine(args, g, n_sources: int):
     return HybridMsBfsEngine(g, num_planes=planes, **lanes_kw)
 
 
-def _run_multi_source(args, g, golden) -> int:
+def _run_multi_source(args, g, golden, on_result=None) -> int:
     """--multi-source path: <source> plus the listed keys, one packed batch."""
     import numpy as np
 
@@ -463,10 +463,16 @@ def _run_multi_source(args, g, golden) -> int:
         out = np.empty((len(sources), g.num_vertices), np.int32)
         np.save(args.save_parent, res.parents_into(out))
     _finish_obs(args, engine, type(engine).__name__)
+    if on_result is not None:
+        on_result(g, engine, res)
     return 0
 
 
-def main(argv=None) -> int:
+def main(argv=None, *, on_result=None) -> int:
+    """Run the CLI on ``argv``. In-process callers may pass
+    ``on_result(graph, engine, result)``, called once after the run's own
+    validation — how a caller checks more than the CLI prints (e.g. more
+    lanes of a batch) without saving the whole result to disk."""
     ap = argparse.ArgumentParser(
         prog="tpu_bfs",
         description="TPU-native distributed BFS (capabilities of Distributed-CUDA-BFS).",
@@ -710,7 +716,9 @@ def main(argv=None) -> int:
 
     from tpu_bfs import validate
     from tpu_bfs.algorithms.bfs import BfsEngine
+    from tpu_bfs.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache(log=lambda m: print(f"[cache] {m}", file=sys.stderr))
     t0 = time.perf_counter()
     from tpu_bfs import obs as obs_mod
 
@@ -755,7 +763,7 @@ def main(argv=None) -> int:
         print(f"Elapsed time in milliseconds (CPU): {(time.perf_counter() - t0) * 1e3:.2f}")
 
     if args.multi_source:
-        return _run_multi_source(args, g, golden)
+        return _run_multi_source(args, g, golden, on_result)
 
     def make_engine():
         if args.mesh:
@@ -882,6 +890,8 @@ def main(argv=None) -> int:
     if args.save_parent and res.parent is not None:
         np.save(args.save_parent, res.parent)
     _finish_obs(args, engine, type(engine).__name__)
+    if on_result is not None:
+        on_result(g, engine, res)
     return 0
 
 

@@ -67,6 +67,24 @@ class KernelWidthError(ValueError):
     cannot express on real hardware (legal widths named in the message)."""
 
 
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode: an explicit choice wins; otherwise True on
+    the ``cpu`` platform (the tests' emulation path), False on ``tpu``,
+    and an error on any other platform — no silent fallback that would
+    run the whole stack through the interpreter and hide the device."""
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run on 'tpu' (compiled) or 'cpu' (interpreted); "
+        f"the default backend is {platform!r}"
+    )
+
+
 def validate_kernel_width(w: int, interpret: bool, *, kernel: str) -> None:
     """Call-boundary width check shared by the Pallas kernels: any
     ``w >= 1`` under ``interpret=True`` (the CPU test path); on a real
@@ -90,10 +108,10 @@ def _ell_expand_kernel(*refs, k: int, w: int, op: str, has_wt: bool):
     """One grid step = one 128-row output tile of one bucket.
 
     Refs (has_wt inserts wt_ref/wt_buf): need_ref [nb] i32 scalar
-    prefetch; gt_ref [k, nb*TILE] i32 and fw_ref [rows, w] stay in HBM;
+    prefetch; gt_ref [k, nb*TILE] i32 and fw_ref [rows, 1, w] stay in HBM;
     out_ref is the [TILE, w] VMEM block; scratch = idx_buf SMEM [k,
     TILE] (slab of row ids — DMA start offsets must be scalar reads),
-    (wt_buf VMEM [k, TILE],) row_buf VMEM [2, TILE, w] (double-buffered
+    (wt_buf VMEM [k, TILE],) row_buf VMEM [2, TILE, 1, w] (double-buffered
     gather landing zone), sems DMA[4] (0 idx slab, 1 wt slab, 2/3 the
     two row slots — each row slot streams TILE same-size copies through
     one semaphore and waits them in issue order)."""
@@ -131,9 +149,12 @@ def _ell_expand_kernel(*refs, k: int, w: int, op: str, has_wt: bool):
         def row_cp(kk, r, slot):
             # One gathered frontier row: fw[gt[kk, j*TILE + r]] -> the
             # landing slot. Same descriptor rebuilt for start and wait.
+            # Rows are sliced along the untiled leading dim of the
+            # [rows, 1, w] view: Mosaic refuses a one-row slice of the
+            # sublane-tiled dim of a 2D [rows, w] ref.
             return pltpu.make_async_copy(
-                fw_ref.at[pl.ds(idx_buf[kk, r], 1), :],
-                row_buf.at[slot, pl.ds(r, 1), :],
+                fw_ref.at[pl.ds(idx_buf[kk, r], 1)],
+                row_buf.at[slot, pl.ds(r, 1)],
                 sems.at[2 + slot],
             )
 
@@ -163,11 +184,14 @@ def _ell_expand_kernel(*refs, k: int, w: int, op: str, has_wt: bool):
             if kk + 1 < k:
                 start_slab(kk + 1)  # hide slab kk+1's gathers behind kk
             wait_slab(kk)
-            rows = row_buf[kk % 2]
+            rows = row_buf[kk % 2].reshape(TILE, w)
             if op == "or":
                 out_ref[:] = out_ref[:] | rows
             elif op == "min":
-                out_ref[:] = jnp.minimum(out_ref[:], rows)
+                # Select, not jnp.minimum: Mosaic has no unsigned min
+                # (arith.minui) on TPU, but it does have unsigned compare.
+                acc = out_ref[:]
+                out_ref[:] = jnp.where(rows < acc, rows, acc)
             else:  # minplus: per-output-row weight add, then min
                 wcol = wt_buf[kk, :].reshape(TILE, 1)
                 out_ref[:] = jnp.minimum(out_ref[:], rows + wcol)
@@ -209,7 +233,7 @@ def ell_expand(need_blk, gt, fw, wt=None, *, w: int, op: str = "or",
     if has_wt:
         scratch.append(pltpu.VMEM((k, TILE), jnp.int32))
     scratch += [
-        pltpu.VMEM((2, TILE, w), dt),
+        pltpu.VMEM((2, TILE, 1, w), dt),
         pltpu.SemaphoreType.DMA((4,)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -221,6 +245,7 @@ def ell_expand(need_blk, gt, fw, wt=None, *, w: int, op: str = "or",
         ),
         scratch_shapes=scratch,
     )
+    fw = fw.reshape(fw.shape[0], 1, w)  # row DMAs slice the leading dim
     args = (need_blk, gt, wt, fw) if has_wt else (need_blk, gt, fw)
     return pl.pallas_call(
         functools.partial(

@@ -25,7 +25,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_bfs.parallel.compat import shard_map
+from jax import shard_map
 
 from tpu_bfs.algorithms.bfs import BfsResult
 from tpu_bfs.algorithms.frontier import (

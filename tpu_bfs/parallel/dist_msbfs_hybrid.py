@@ -59,7 +59,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_bfs.parallel.compat import shard_map
+from jax import shard_map
 
 from tpu_bfs.graph.csr import Graph
 from tpu_bfs.graph.ell import (
@@ -84,6 +84,7 @@ from tpu_bfs.algorithms._packed_common import (
 )
 from tpu_bfs.algorithms.msbfs_hybrid import fill_a_tiles, select_dense_tiles
 from tpu_bfs.ops.tile_spmm import AW, TILE, tile_spmm
+from tpu_bfs.ops.ell_expand import resolve_interpret
 from tpu_bfs.parallel.collectives import (
     RowGatherExchangeAccounting,
     check_delta_bits,
@@ -857,8 +858,7 @@ class DistHybridMsBfsEngine(
         self.lanes = lanes
         self.num_planes = num_planes
         self.max_levels_cap = min(1 << num_planes, 254)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
         self.mesh = mesh if isinstance(mesh, Mesh) else make_mesh(mesh)
         p_count = self.mesh.devices.size
         layout = "sliced" if exchange == "sliced" else "gather"

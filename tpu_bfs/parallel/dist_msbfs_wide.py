@@ -32,10 +32,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_bfs.parallel.compat import shard_map
+from jax import shard_map
 
 from tpu_bfs.graph.csr import Graph
 from tpu_bfs.graph.ell import ShardedEllGraph, build_ell_sharded
+from tpu_bfs.ops.ell_expand import resolve_interpret
 from tpu_bfs.algorithms.msbfs_packed import ripple_increment
 from tpu_bfs.algorithms._packed_common import (
     AotProgramProtocol,
@@ -268,11 +269,7 @@ class DistWideMsBfsEngine(PackedRunProtocol, RowGatherExchangeAccounting,
             raise ValueError("num_planes must be in [1, 8]")
         validate_expand_impl(expand_impl)
         self.expand_impl = expand_impl
-        if interpret is None:
-            # Same resolution as the hybrid engines' kernels: emulate the
-            # Pallas tier off-TPU so the CPU fuzz drives the real kernel
-            # inside shard_map.
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
         self._interpret = bool(interpret)
         if exchange not in ("dense", "sparse"):
             raise ValueError(

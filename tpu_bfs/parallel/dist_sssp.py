@@ -52,6 +52,7 @@ from tpu_bfs import faults as _faults
 from tpu_bfs.algorithms._packed_common import ExpandSpec
 from tpu_bfs.graph.csr import Graph
 from tpu_bfs.graph.ell import build_ell_sharded, build_ell_weights_sharded
+from tpu_bfs.ops.ell_expand import resolve_interpret
 from tpu_bfs.parallel.collectives import (
     check_delta_bits,
     default_row_gather_caps,
@@ -63,7 +64,7 @@ from tpu_bfs.parallel.collectives import (
     ring_reduce_scatter,
     sparse_rows_exchange_min,
 )
-from tpu_bfs.parallel.compat import shard_map
+from jax import shard_map
 from tpu_bfs.parallel.dist_bfs import make_mesh
 from tpu_bfs.utils.aot import AotProgramProtocol
 from tpu_bfs.workloads.sssp import (
@@ -285,8 +286,7 @@ class DistSsspEngine(AotProgramProtocol):
 
         validate_expand_impl(expand_impl)
         self.expand_impl = expand_impl
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
         self._interpret = bool(interpret)
         if not isinstance(graph, Graph):
             raise ValueError(

@@ -44,7 +44,7 @@ def _heartbeat_fn(devices: int):
     from jax import lax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from tpu_bfs.parallel.compat import shard_map
+    from jax import shard_map
 
     avail = jax.devices()
     if devices > len(avail):

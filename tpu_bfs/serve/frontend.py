@@ -2673,14 +2673,16 @@ def run_server(args, stdin=None, stdout=None, stderr=None,
             log(f"metricz write failed ({exc!r})")
 
     stop_statsz = threading.Event()
+    statsz_thread = None
     if statsz_interval > 0:
         def statsz_loop() -> None:
             while not stop_statsz.wait(statsz_interval):
                 emit_telemetry()
 
-        threading.Thread(
+        statsz_thread = threading.Thread(
             target=statsz_loop, name="bfs-serve-statsz", daemon=True
-        ).start()
+        )
+        statsz_thread.start()
 
     log(f"serving {args.graph!r}: engine={args.engine} lanes={args.lanes} "
         f"ladder={service.width_ladder} "
@@ -2809,6 +2811,10 @@ def run_server(args, stdin=None, stdout=None, stderr=None,
             if outstanding[0] > 0:
                 log(f"drain timeout: {outstanding[0]} responses unemitted")
         stop_statsz.set()
+        if statsz_thread is not None:
+            # Joined, not left to interpreter exit: a daemon thread still
+            # waking at finalization aborted the process (rc 134).
+            statsz_thread.join()
         emit_telemetry()  # the final statsz line + --metricz-out text
         if xprof:
             import jax

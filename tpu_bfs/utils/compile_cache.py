@@ -1,65 +1,69 @@
-"""Persistent XLA compilation cache, shared by bench.py and the
-measurement scripts (scripts/width_probe.py).
+"""Persistent XLA compilation cache, shared by every entry point: the CLI,
+bench.py, the serve registry, chip_smoke.py and the measurement scripts.
 
-First compiles of the packed level loop cost ~20-40 s on the chip and
-recur in every fresh process; during an outage-recovery session that is
-wall-clock the bench's budget envelope cannot spare. One copy of the
-env-var resolution so the two callers cannot drift into writing separate
-caches (TPU_BFS_BENCH_XLA_CACHE, default <TPU_BFS_BENCH_CACHE>/xla_cache;
-empty disables).
+Where the cache lives is decided outside the program when it can be. If
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this module
+sets nothing. Otherwise the cache sits at one fixed absolute path inside
+the checkout, ``<repo root>/.bench_cache/xla_cache``, built from this
+file's location and not from the cwd: a directory that moves with the
+cwd never hits.
 
 Resolution is ONCE PER PROCESS: every ``EngineRegistry()`` construction
-and every bench entry calls :func:`enable_compile_cache`, and before the
-idempotency guard each call re-ran ``jax.config.update`` and re-logged
-the path — a preheat run constructing registries per service spammed the
-log and re-pointed jax at a cache it was already using. The first call's
-outcome (path or disabled) is cached; later calls return it silently.
-``force=True`` re-resolves (tests that vary the env).
+and every entry point calls :func:`enable_compile_cache`; the first
+call's outcome (path or unavailable) is cached and later calls return it
+silently. ``force=True`` re-resolves (tests that vary the env).
 """
 
 from __future__ import annotations
 
 import os
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: The in-checkout default: <repo root>/.bench_cache/xla_cache.
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".bench_cache",
+    "xla_cache",
+)
+
 # The first call's resolved outcome, kept as a 1-tuple so a resolved
-# "disabled" (None) is distinguishable from "never resolved".
+# "unavailable" (None) is distinguishable from "never resolved".
 _RESOLVED: tuple | None = None
 
 
 def reset_resolution() -> None:
-    """Forget the cached resolution (tests that vary the env vars)."""
+    """Forget the cached resolution (tests that vary the env)."""
     global _RESOLVED
     _RESOLVED = None
 
 
 def enable_compile_cache(log=None, *, force: bool = False) -> str | None:
-    """Point jax at the persistent compile cache; best-effort and
-    idempotent (resolved once per process — see module docstring).
+    """Arm the persistent compile cache; best-effort and idempotent
+    (resolved once per process — see module docstring).
 
-    Returns the cache path when enabled, None when disabled or
-    unavailable (a jax without the knob degrades to the status quo).
+    Returns the cache path in use, or None when jax rejected the knob
+    (the cache is an optimization, never a dependency).
     """
     global _RESOLVED
     if _RESOLVED is not None and not force:
         return _RESOLVED[0]
-    path = os.environ.get(
-        "TPU_BFS_BENCH_XLA_CACHE",
-        os.path.join(
-            os.environ.get("TPU_BFS_BENCH_CACHE", ".bench_cache"), "xla_cache"
-        ),
-    )
-    if not path:
-        _RESOLVED = (None,)
-        return None
+    from_env = os.environ.get(ENV_VAR)
+    if from_env:
+        # JAX's own handling: it read the variable at import.
+        if log:
+            log(f"persistent compile cache: {from_env} (from {ENV_VAR})")
+        _RESOLVED = (from_env,)
+        return from_env
     try:
-        os.makedirs(path, exist_ok=True)
+        os.makedirs(DEFAULT_DIR, exist_ok=True)
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
         if log:
-            log(f"persistent compile cache: {path}")
-        _RESOLVED = (path,)
-        return path
+            log(f"persistent compile cache: {DEFAULT_DIR}")
+        _RESOLVED = (DEFAULT_DIR,)
+        return DEFAULT_DIR
     except Exception as exc:  # noqa: BLE001 — the cache is an optimization
         if log:
             log(f"compile cache unavailable ({exc!r}); continuing without")

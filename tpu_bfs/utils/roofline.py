@@ -50,7 +50,23 @@ from tpu_bfs.obs.engine_trace import trace_summary as _trace_summary
 from tpu_bfs.ops.tile_spmm import TILE, tile_spmm
 from tpu_bfs.utils.timing import run_timed
 
-V5E_PEAK_GBS = 819.0  # HBM2 bandwidth of one v5e chip, vendor figure
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+#: Google Cloud documentation, "TPU v5e" (819 GB/s HBM, 197 TFLOP/s bf16).
+PEAKS = {
+    "TPU v5 lite": {"hbm_gbs": 819.0, "bf16_tflops": 197.0},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of ``device_kind``; a device not in
+    the table is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); pass the peak explicitly"
+        ) from None
 
 
 def phase_fns(engine) -> dict:
@@ -321,7 +337,7 @@ class LevelAttribution:
     exchange_branch: int | None = None
 
 
-def roofline_hybrid(engine, sources, *, peak_gbs: float = V5E_PEAK_GBS,
+def roofline_hybrid(engine, sources, *, peak_gbs: float | None = None,
                     measured_gteps: float | None = None,
                     log=None) -> dict:
     """Attribute a real traversal of ``sources`` level by level.
@@ -330,7 +346,11 @@ def roofline_hybrid(engine, sources, *, peak_gbs: float = V5E_PEAK_GBS,
     with shares and achieved GB/s, the fusion dividend, the named binding
     term, and the peak-bandwidth ceiling implied by the byte model (scaled
     from ``measured_gteps`` when given — pass the timed batch's figure so
-    the ceiling is anchored to the same run protocol)."""
+    the ceiling is anchored to the same run protocol). ``peak_gbs``
+    defaults to the published HBM peak of the default device
+    (:func:`device_peaks`)."""
+    if peak_gbs is None:
+        peak_gbs = device_peaks(jax.devices()[0].device_kind)["hbm_gbs"]
     fns = phase_fns(engine)
     arrs = engine.arrs
     sources = np.asarray(sources)

@@ -275,9 +275,13 @@ def check_planned_sparse(graph, p: int = 8, wire_pack: bool = False) -> dict:
         and all(c.result_bytes == chunk for c in perms)
     )
     # Scalars: two s32[2] pmax pairs (pre/post-sieve measure), plus the
-    # 4-byte termination psum and visited-total seed.
-    pairs = [c for c in pool if c.op == "all-reduce" and c.result_bytes == 8]
-    singles = [c for c in pool if c.op == "all-reduce" and c.result_bytes == 4]
+    # 4-byte termination psum and visited-total seed. XLA's all-reduce
+    # combiner may fuse independent scalars into one (s32[], s32[])
+    # tuple: 8 bytes in two pieces, which is not a pmax pair.
+    pairs = [c for c in pool if c.op == "all-reduce" and c.result_bytes == 8
+             and c.pieces == 1]
+    singles = [c for c in pool if c.op == "all-reduce"
+               and c.result_bytes == 4 * c.pieces]
 
     sparse_wire = [(p - 1) * piece for piece in piece_bytes]
     ring_wire = float((p - 1) * chunk)
@@ -788,7 +792,7 @@ def check_wire_checksum(p: int = 8, words: int = 64) -> dict:
     from jax.sharding import Mesh, PartitionSpec as P
 
     from tpu_bfs.integrity.wire import checksummed_ring_or
-    from tpu_bfs.parallel.compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()[:p]
     mesh = Mesh(np.array(devs), ("x",))
@@ -803,6 +807,7 @@ def check_wire_checksum(p: int = 8, words: int = 64) -> dict:
 
         fn = shard_map(
             body, mesh=mesh, in_specs=P("x"), out_specs=(P("x"), P("x")),
+            check_vma=False,
         )
         return jax.jit(fn).lower(chunks).compile().as_text()
 

@@ -42,6 +42,7 @@ import numpy as np
 from tpu_bfs import faults as _faults
 from tpu_bfs.graph.csr import INF_DIST, Graph
 from tpu_bfs.graph.ell import build_ell, build_ell_weights
+from tpu_bfs.ops.ell_expand import resolve_interpret
 
 #: On-device "unreached" tentative distance. 2**29 keeps every sum the
 #: expansion forms (dist + weight, each <= INF_W) under 2**30, far from
@@ -212,8 +213,7 @@ class SsspEngine:
         validate_expand_impl(expand_impl)
         self.overlay = tuple(int(x) for x in overlay) if overlay else ()
         self.expand_impl = expand_impl
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret(interpret)
         self._interpret = bool(interpret)
         if graph.weights is None:
             raise ValueError(

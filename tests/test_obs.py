@@ -581,6 +581,22 @@ def obs_spy(monkeypatch):
         et, "record_packed_run",
         lambda *a, **kw: calls.append("record_packed_run"),
     )
+    # The per-batch host spans: with no profiler and no recorder, each is
+    # the shared no-op span and never computes its stats.
+    host_span = obs.host_span
+
+    def spied_span(name, stats=None, **kw):
+        def counted_stats():
+            calls.append(f"stats:{name}")
+            return stats()
+
+        span = host_span(name, None if stats is None else counted_stats,
+                         **kw)
+        if span is not obs._NULL_SPAN:
+            calls.append(f"span_on:{name}")
+        return span
+
+    monkeypatch.setattr(obs, "host_span", spied_span)
     return calls
 
 
@@ -600,6 +616,48 @@ def test_disarmed_dispatch_fetch_make_zero_obs_calls(obs_registry, obs_spy):
     res = engine.fetch(pend)
     assert int(res.reached[0]) > 0
     assert obs_spy == []
+
+
+def test_disarmed_host_spans_are_per_batch_not_per_query(obs_registry,
+                                                       monkeypatch):
+    """One serve batch and one engine.run with no profiler and no
+    recorder: the host spans are one call per batch step whatever the
+    batch's size, and none computes its stats."""
+    from tpu_bfs.serve.executor import BatchExecutor
+    from tpu_bfs.serve.scheduler import AdmissionQueue, PendingQuery
+
+    calls = []
+    host_span = obs.host_span
+
+    def spied_span(name, stats=None, **kw):
+        calls.append(name)
+        return host_span(
+            name, None if stats is None
+            else (lambda: calls.append(f"stats:{name}") or stats()), **kw)
+
+    monkeypatch.setattr(obs, "host_span", spied_span)
+    svc = _svc(obs_registry, autostart=False)
+    engine = svc._registry.get(svc._spec())
+    executor = BatchExecutor(ServeMetrics())
+    per_batch = []
+    for sources in ((0,), (0, 3, 5, 9, 17, 33, 65)):
+        calls.clear()
+        queue = AdmissionQueue(64)
+        for s in sources:
+            queue.offer(PendingQuery(s, want_distances=False))
+        queries = queue.next_batch(engine.lanes, 0.0)
+        executor.run_batch(engine, queries)
+        assert all(q.result(0).ok for q in queries)
+        per_batch.append(sorted(calls))
+    assert per_batch[0] == per_batch[1] == sorted([
+        "tpu_bfs.serve.coalesce", "tpu_bfs.serve.dispatch",
+        "tpu_bfs.engine.dispatch", "tpu_bfs.serve.fetch",
+        "tpu_bfs.engine.fetch", "tpu_bfs.serve.extract",
+        "tpu_bfs.serve.write",
+    ])
+    calls.clear()
+    engine.run(np.zeros(engine.lanes, dtype=np.int64))
+    assert calls == ["tpu_bfs.engine.dispatch", "tpu_bfs.engine.fetch"]
 
 
 def test_armed_serve_records_complete_span_chains(obs_registry, tmp_path):

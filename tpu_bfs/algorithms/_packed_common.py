@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from functools import partial
+from functools import partial, wraps
 from typing import NamedTuple
 
 import jax
@@ -29,7 +29,11 @@ import numpy as np
 from tpu_bfs import faults as _faults
 from tpu_bfs import obs as _obs
 from tpu_bfs.graph.csr import INF_DIST
-from tpu_bfs.algorithms.msbfs_packed import UNREACHED, ripple_increment
+from tpu_bfs.algorithms.msbfs_packed import (
+    UNREACHED,
+    fold_heavy,
+    ripple_increment,
+)
 
 
 def floor_lanes(lanes: int) -> int:
@@ -204,6 +208,19 @@ def row_unsettled(vis, act: int, lane_mask):
     return uns & (jax.lax.iota(jnp.int32, rows) < act)
 
 
+def _lanes_scoped(fn):
+    """Trace a level-loop core ``fn(arrs, fw, ...)`` inside the
+    ``lanes<W>`` scope of its [rows, w] frontier table (W = 32 w), so each
+    width's ops are told apart in a trace (the serve ladder's rungs)."""
+
+    @wraps(fn)
+    def scoped(arrs, fw, *args, **kwargs):
+        with _obs.scope(f"lanes{32 * fw.shape[1]}"):
+            return fn(arrs, fw, *args, **kwargs)
+
+    return scoped
+
+
 def make_gated_fori_expand(spec: "ExpandSpec", w: int, *, combine=None,
                            identity: int = 0):
     """Frontier-gated bucketed-ELL expansion — make_fori_expand's shape,
@@ -232,6 +249,7 @@ def make_gated_fori_expand(spec: "ExpandSpec", w: int, *, combine=None,
 
     heavy_blocks = -(-spec.num_virtual // T) if spec.heavy else 0
 
+    @_obs.scope("level.gather")
     def expand(arrs, fw, needed):
         parts = []
         skipped = jnp.int32(0)
@@ -247,17 +265,8 @@ def make_gated_fori_expand(spec: "ExpandSpec", w: int, *, combine=None,
                 acc = jax.lax.fori_loop(
                     0, spec.kcap, vbody, _full((spec.num_virtual, w))
                 )
-                vr_ext = jnp.concatenate([acc, _full((1, w))])
-                cur = vr_ext[arrs["fold_pad_map"]]
-                pyramid = [cur]
-                for _ in range(spec.fold_steps):
-                    pairs = cur.reshape(-1, 2, w)
-                    cur = combine(pairs[:, 0], pairs[:, 1])
-                    pyramid.append(cur)
-                pyr = (
-                    jnp.concatenate(pyramid) if len(pyramid) > 1 else pyramid[0]
-                )
-                return pyr[arrs["heavy_pick"]]
+                return fold_heavy(acc, arrs, combine, _full((1, w)),
+                                  spec.fold_steps)
 
             h_need = jnp.any(needed[:nh])
             parts.append(
@@ -500,23 +509,32 @@ def make_packed_loop(hit_of, num_planes: int, *, gate_levels: int = 0,
 
         def body(carry):
             fw, vis, planes, level, _, gc = carry
-            hit, skipped = call_hit(arrs, fw, vis, lane_mask)
-            if gated:
-                need = row_unsettled(vis, act, lane_mask)
-                nxt, vis2, planes = gated_state_update(hit, vis, planes, need)
-                gc = gc.at[jnp.minimum(level, gate_levels - 1)].set(skipped)
-            else:
-                nxt = hit & ~vis
-                vis2 = vis | nxt
-                # Pad/sentinel rows count up harmlessly (never visited,
-                # sliced off at extraction).
-                planes = ripple_increment(planes, ~vis2)
-            alive = jnp.any(nxt != 0)
-            return nxt, vis2, planes, level + 1, alive, gc
+            with _obs.scope("level.gather"):
+                hit, skipped = call_hit(arrs, fw, vis, lane_mask)
+            with _obs.scope("level.claim"):
+                if gated:
+                    need = row_unsettled(vis, act, lane_mask)
+                    nxt, vis2, planes = gated_state_update(
+                        hit, vis, planes, need
+                    )
+                    gc = gc.at[jnp.minimum(level, gate_levels - 1)].set(
+                        skipped
+                    )
+                else:
+                    nxt = hit & ~vis
+                    vis2 = vis | nxt
+                    # Pad/sentinel rows count up harmlessly (never
+                    # visited, sliced off at extraction).
+                    planes = ripple_increment(planes, ~vis2)
+                alive = jnp.any(nxt != 0)
+                return nxt, vis2, planes, level + 1, alive, gc
 
-        return jax.lax.while_loop(
-            cond, body, (fw, vis, planes, level0, jnp.bool_(True), gc)
-        )
+        # The loop itself (its termination test and carry) is claim work;
+        # each phase of the body names itself inside it.
+        with _obs.scope("level.claim"):
+            return jax.lax.while_loop(
+                cond, body, (fw, vis, planes, level0, jnp.bool_(True), gc)
+            )
 
     def _truncated(arrs, fw_f, vis_f, levels, alive, max_levels, lane_mask):
         # `alive` only says the last body claimed something. When the loop
@@ -526,12 +544,19 @@ def make_packed_loop(hit_of, num_planes: int, *, gate_levels: int = 0,
         # expand, so a traversal whose eccentricity lands exactly on the
         # cap does not falsely report truncation.
         def deeper():
-            hit = call_hit(arrs, fw_f, vis_f, lane_mask)[0]
+            with _obs.scope("level.gather"):
+                hit = call_hit(arrs, fw_f, vis_f, lane_mask)[0]
             return jnp.any((hit & ~vis_f) != 0)
 
-        return jax.lax.cond(
-            alive & (levels >= max_levels), deeper, lambda: jnp.bool_(False)
-        )
+        with _obs.scope("level.claim"):
+            return jax.lax.cond(
+                alive & (levels >= max_levels), deeper,
+                lambda: jnp.bool_(False),
+            )
+
+    def _planes0(fw0):
+        with _obs.scope("batch.seed"):
+            return tuple(jnp.zeros_like(fw0) for _ in range(num_planes))
 
     def _gc0():
         return jnp.zeros((max(gate_levels, 1),), jnp.int32)
@@ -539,8 +564,9 @@ def make_packed_loop(hit_of, num_planes: int, *, gate_levels: int = 0,
     if gated:
 
         @jax.jit  # no-donate: fw0 doubles as the batch's src-bits view (fetch reads it after the loop)
+        @_lanes_scoped
         def core(arrs, fw0, max_levels, lane_mask):
-            planes0 = tuple(jnp.zeros_like(fw0) for _ in range(num_planes))
+            planes0 = _planes0(fw0)
             fw_f, vis_f, planes_f, levels, alive, gc = _run(
                 arrs, fw0, fw0, planes0, jnp.int32(0), max_levels,
                 lane_mask, _gc0(),
@@ -551,12 +577,14 @@ def make_packed_loop(hit_of, num_planes: int, *, gate_levels: int = 0,
             return planes_f, vis_f, levels, alive, truncated, gc
 
         @jax.jit  # no-donate: the cap-boundary probe and roofline re-read their carries; advance rides core_from_donate
+        @_lanes_scoped
         def core_from(arrs, fw, vis, planes, level0, max_levels, lane_mask):
             return _run(
                 arrs, fw, vis, planes, level0, max_levels, lane_mask, _gc0()
             )
 
         @partial(jax.jit, donate_argnums=(1, 2, 3))
+        @_lanes_scoped
         def core_from_donate(arrs, fw, vis, planes, level0, max_levels,
                              lane_mask):
             return _run(
@@ -567,8 +595,9 @@ def make_packed_loop(hit_of, num_planes: int, *, gate_levels: int = 0,
         return core, core_from, core_from_donate
 
     @jax.jit  # no-donate: fw0 doubles as the batch's src-bits view (fetch reads it after the loop)
+    @_lanes_scoped
     def core(arrs, fw0, max_levels):
-        planes0 = tuple(jnp.zeros_like(fw0) for _ in range(num_planes))
+        planes0 = _planes0(fw0)
         fw_f, vis_f, planes_f, levels, alive, _ = _run(
             arrs, fw0, fw0, planes0, jnp.int32(0), max_levels, None, _gc0()
         )
@@ -578,11 +607,13 @@ def make_packed_loop(hit_of, num_planes: int, *, gate_levels: int = 0,
         return planes_f, vis_f, levels, alive, truncated
 
     @jax.jit  # no-donate: the cap-boundary probe and roofline re-read their carries; advance rides core_from_donate
+    @_lanes_scoped
     def core_from(arrs, fw, vis, planes, level0, max_levels):
         out = _run(arrs, fw, vis, planes, level0, max_levels, None, _gc0())
         return out[:5]
 
     @partial(jax.jit, donate_argnums=(1, 2, 3))
+    @_lanes_scoped
     def core_from_donate(arrs, fw, vis, planes, level0, max_levels):
         out = _run(arrs, fw, vis, planes, level0, max_levels, None, _gc0())
         return out[:5]
@@ -625,6 +656,7 @@ def make_fori_expand(spec: ExpandSpec, w: int, *, combine=None,
     def _full(shape):
         return jnp.full(shape, ident, jnp.uint32)
 
+    @_obs.scope("level.gather")
     def expand(arrs, fw):
         parts = []
         if spec.heavy:
@@ -636,15 +668,8 @@ def make_fori_expand(spec: ExpandSpec, w: int, *, combine=None,
             acc = jax.lax.fori_loop(
                 0, spec.kcap, vbody, _full((spec.num_virtual, w))
             )
-            vr_ext = jnp.concatenate([acc, _full((1, w))])
-            cur = vr_ext[arrs["fold_pad_map"]]
-            pyramid = [cur]
-            for _ in range(spec.fold_steps):
-                pairs = cur.reshape(-1, 2, w)
-                cur = combine(pairs[:, 0], pairs[:, 1])
-                pyramid.append(cur)
-            pyr = jnp.concatenate(pyramid) if len(pyramid) > 1 else pyramid[0]
-            parts.append(pyr[arrs["heavy_pick"]])
+            parts.append(fold_heavy(acc, arrs, combine, _full((1, w)),
+                                    spec.fold_steps))
         for i, (k, n) in enumerate(spec.light_meta):
             bt = arrs[f"light{i}_t"]  # [k, n]
 
@@ -766,21 +791,15 @@ def make_pallas_expand(spec: "ExpandSpec", w: int, *, op: str = "or",
         )
         return out[:n]
 
+    @_obs.scope("level.gather")
     def expand(arrs, fw):
         parts = []
         if spec.heavy:
             acc = _bucket(
                 arrs, fw, "virtual", spec.kcap, spec.num_virtual
             )
-            vr_ext = jnp.concatenate([acc, _full((1, w))])
-            cur = vr_ext[arrs["fold_pad_map"]]
-            pyramid = [cur]
-            for _ in range(spec.fold_steps):
-                pairs = cur.reshape(-1, 2, w)
-                cur = combine(pairs[:, 0], pairs[:, 1])
-                pyramid.append(cur)
-            pyr = jnp.concatenate(pyramid) if len(pyramid) > 1 else pyramid[0]
-            parts.append(pyr[arrs["heavy_pick"]])
+            parts.append(fold_heavy(acc, arrs, combine, _full((1, w)),
+                                    spec.fold_steps))
         for i, (k, n) in enumerate(spec.light_meta):
             parts.append(_bucket(arrs, fw, f"light{i}", k, n))
         if spec.tail_rows:
@@ -815,6 +834,7 @@ def make_gated_pallas_expand(spec: "ExpandSpec", w: int, *, op: str = "or",
 
     heavy_blocks = -(-spec.num_virtual // T) if spec.heavy else 0
 
+    @_obs.scope("level.gather")
     def expand(arrs, fw, needed):
         parts = []
         skipped = jnp.int32(0)
@@ -829,17 +849,8 @@ def make_gated_pallas_expand(spec: "ExpandSpec", w: int, *, op: str = "or",
                     jnp.ones((nvb,), jnp.int32), gt, fw,
                     w=w, op=op, interpret=interpret,
                 )[: spec.num_virtual]
-                vr_ext = jnp.concatenate([acc, _full((1, w))])
-                cur = vr_ext[arrs["fold_pad_map"]]
-                pyramid = [cur]
-                for _ in range(spec.fold_steps):
-                    pairs = cur.reshape(-1, 2, w)
-                    cur = combine(pairs[:, 0], pairs[:, 1])
-                    pyramid.append(cur)
-                pyr = (
-                    jnp.concatenate(pyramid) if len(pyramid) > 1 else pyramid[0]
-                )
-                return pyr[arrs["heavy_pick"]]
+                return fold_heavy(acc, arrs, combine, _full((1, w)),
+                                  spec.fold_steps)
 
             h_need = jnp.any(needed[:nh])
             parts.append(
@@ -946,11 +957,13 @@ def make_adaptive_hit(hit_of, act: int, w: int, out_rows: int, push_cfg):
     row_cap, _ = push_cfg
 
     def adaptive(arrs, fw):
-        rows_active = jnp.any(fw[:act] != 0, axis=1)
-        nz = jnp.sum(rows_active.astype(jnp.int32))
-        bad = jnp.any(rows_active & arrs["push_inelig"])
-        light = (nz <= row_cap) & ~bad
+        with _obs.scope("level.push"):
+            rows_active = jnp.any(fw[:act] != 0, axis=1)
+            nz = jnp.sum(rows_active.astype(jnp.int32))
+            bad = jnp.any(rows_active & arrs["push_inelig"])
+            light = (nz <= row_cap) & ~bad
 
+        @_obs.scope("level.push")
         def push_fn():
             idx = jnp.where(rows_active, size=row_cap, fill_value=act)[0]
             pt = arrs["push_t"]
@@ -1049,12 +1062,14 @@ def make_state_kernels(
         blocks, in_deg = ((0, act),), None
 
     @jax.jit
+    @_obs.scope("batch.seed")
     def seed(rws, words, bits):
         # Distinct lanes own distinct (word, bit) pairs, so scatter-add == OR.
         fw0 = jnp.zeros((rows, w), jnp.uint32)
         return fw0.at[rws, words].add(bits)
 
     @jax.jit
+    @_obs.scope("batch.stats")
     def lane_stats(vis):
         """Per-word-column reached count and degree sum, on device.
 
@@ -1090,6 +1105,7 @@ def make_state_kernels(
         return jax.lax.fori_loop(0, w, wbody, (r0, d0))
 
     @jax.jit
+    @_obs.scope("batch.stats")
     def extract_word(planes, vis, src_bits, wi):
         """Distances of word-column wi's 32 lanes as [act, 32] uint8."""
         shifts = jnp.arange(32, dtype=jnp.uint32)
@@ -1107,6 +1123,7 @@ def make_state_kernels(
         )
 
     @jax.jit
+    @_obs.scope("batch.stats")
     def lane_ecc(planes, vis, src_bits):
         """Per-lane eccentricity (max finite distance) as [w, 32] int32.
 
@@ -1917,7 +1934,9 @@ def dispatch_packed_batch(
     sources = _check_batch_sources(engine, sources)
     cap = engine.max_levels_cap
     max_levels = cap if max_levels is None else min(max_levels, cap)
-    with _engine_dispatch_lock(engine):
+    with _obs.host_span("tpu_bfs.engine.dispatch", lambda: {
+        "lanes": engine.lanes, "n": len(sources), "max_levels": max_levels,
+    }), _engine_dispatch_lock(engine):
         # Same pull-gate hook as advance_packed_batch: the gated cores
         # need the batch's active-lane mask before dispatch. The mask is
         # bound into the core call inside the lock, so a concurrent
@@ -1951,24 +1970,28 @@ def fetch_packed_batch(
             "fetch", lanes=engine.lanes,
             devices=_faults.mesh_devices(engine),
         )
-    levels = int(pend.levels)  # blocks until the loop finishes
-    elapsed = (time.perf_counter() - pend.t0) if time_it else None
-    engine._warmed = True
-    if (
-        check_cap
-        and bool(pend.truncated)
-        and pend.max_levels == engine.max_levels_cap
-    ):
-        raise RuntimeError(
-            f"traversal truncated at {levels} levels; "
-            f"num_planes={engine.num_planes} caps at "
-            f"{engine.max_levels_cap} — construct the engine with more "
-            "planes for this graph"
+    levels = None
+    with _obs.host_span("tpu_bfs.engine.fetch", lambda: {
+        "lanes": engine.lanes, "levels": levels,
+    }):
+        levels = int(pend.levels)  # blocks until the loop finishes
+        elapsed = (time.perf_counter() - pend.t0) if time_it else None
+        engine._warmed = True
+        if (
+            check_cap
+            and bool(pend.truncated)
+            and pend.max_levels == engine.max_levels_cap
+        ):
+            raise RuntimeError(
+                f"traversal truncated at {levels} levels; "
+                f"num_planes={engine.num_planes} caps at "
+                f"{engine.max_levels_cap} — construct the engine with more "
+                "planes for this graph"
+            )
+        result = _assemble_packed_result(
+            engine, pend.sources, pend.planes, pend.vis, pend.fw0, levels,
+            bool(pend.alive), elapsed
         )
-    result = _assemble_packed_result(
-        engine, pend.sources, pend.planes, pend.vis, pend.fw0, levels,
-        bool(pend.alive), elapsed
-    )
     if _obs.ACTIVE is not None:
         # Engine-trace assembly (tpu_bfs/obs/engine_trace) reads the gate
         # counter — a device array whose transfer must stay behind the

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_bfs import obs as _obs
 from tpu_bfs.graph.csr import Graph
 from tpu_bfs.graph.ell import (
     EllBucket,
@@ -338,11 +339,16 @@ def _make_core(hg: HybridGraph, w: int, num_planes: int, interpret: bool,
                expand_impl: str = "xla"):
     has_dense = hg.num_tiles > 0
 
-    def dense_pass(arrs, fw):
-        return tile_spmm(
-            arrs["row_start"], arrs["col_tile"], arrs["a_tiles"], fw,
-            num_row_tiles=hg.vt, w=w, interpret=interpret,
-        )
+    def with_dense(hit, arrs, fw):
+        """OR the MXU tile pass's hits into the residual's: the
+        ``level.tiles`` phase."""
+        if not has_dense:
+            return hit
+        with _obs.scope("level.tiles"):
+            return hit | tile_spmm(
+                arrs["row_start"], arrs["col_tile"], arrs["a_tiles"], fw,
+                num_row_tiles=hg.vt, w=w, interpret=interpret,
+            )
 
     if gate_levels:
         # Pull gate (ISSUE 1): residual bucket outputs live in r_order, so
@@ -361,10 +367,9 @@ def _make_core(hg: HybridGraph, w: int, num_planes: int, interpret: bool,
             res, skipped = gated_residual(
                 arrs, fw, need_ext[arrs["gate_fwd"]]
             )
-            hit = res[arrs["inv_perm_ext"]]
-            if has_dense:
-                hit = hit | dense_pass(arrs, fw)
-            return hit, skipped
+            with _obs.scope("level.gather"):
+                hit = res[arrs["inv_perm_ext"]]
+            return with_dense(hit, arrs, fw), skipped
 
         return make_packed_loop(
             hit_of, num_planes, gate_levels=gate_levels, act=hg.num_active
@@ -375,10 +380,9 @@ def _make_core(hg: HybridGraph, w: int, num_planes: int, interpret: bool,
     )
 
     def hit_of(arrs, fw):
-        hit = expand_residual(arrs, fw)[arrs["inv_perm_ext"]]
-        if has_dense:
-            hit = hit | dense_pass(arrs, fw)
-        return hit
+        with _obs.scope("level.gather"):
+            hit = expand_residual(arrs, fw)[arrs["inv_perm_ext"]]
+        return with_dense(hit, arrs, fw)
 
     if push_cfg is not None:
         # Level-adaptive expansion (experimental): light levels skip BOTH

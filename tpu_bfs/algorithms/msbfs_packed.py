@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_bfs import obs as _obs
 from tpu_bfs.graph.csr import Graph, INF_DIST
 from tpu_bfs.graph.ell import EllGraph, build_ell
 from tpu_bfs.utils.aot import AotProgramProtocol
@@ -202,6 +203,23 @@ class PackedBfsResult:
         return out
 
 
+def fold_heavy(acc, arrs, combine, ident_row, fold_steps: int):
+    """The heavy rows' outputs from their virtual rows' (``acc``): the
+    fold pyramid combines each heavy row's virtual rows pairwise, and
+    ``heavy_pick`` reads each heavy row's level of it (graph/ell.py). One
+    copy for every expansion tier, under the ``level.heavy`` scope."""
+    with _obs.scope("level.heavy"):
+        vr_ext = jnp.concatenate([acc, ident_row])
+        cur = vr_ext[arrs["fold_pad_map"]]
+        pyramid = [cur]
+        for _ in range(fold_steps):
+            pairs = cur.reshape(-1, 2, cur.shape[-1])
+            cur = combine(pairs[:, 0], pairs[:, 1])
+            pyramid.append(cur)
+        pyr = jnp.concatenate(pyramid) if len(pyramid) > 1 else pyramid[0]
+        return pyr[arrs["heavy_pick"]]
+
+
 def make_packed_expand(
     *, w: int, kcap: int, fold_steps: int, num_virtual: int,
     light_meta: list[tuple[int, int]], heavy: bool, tail_rows: int,
@@ -214,6 +232,7 @@ def make_packed_expand(
     of (k, n) bucket shapes, ``tail_rows`` the appended all-zero rows.
     """
 
+    @_obs.scope("level.gather")
     def expand(arrs, fw):
         parts = []
         if heavy:
@@ -221,15 +240,9 @@ def make_packed_expand(
             acc = jnp.zeros((num_virtual, w), jnp.uint32)
             for k in range(kcap):
                 acc = acc | fw[vr_t[k]]
-            vr_ext = jnp.concatenate([acc, jnp.zeros((1, w), jnp.uint32)])
-            cur = vr_ext[arrs["fold_pad_map"]]
-            pyramid = [cur]  # level 0: the padded layout itself
-            for _ in range(fold_steps):
-                pairs = cur.reshape(-1, 2, w)
-                cur = pairs[:, 0] | pairs[:, 1]
-                pyramid.append(cur)
-            pyr = jnp.concatenate(pyramid) if len(pyramid) > 1 else pyramid[0]
-            parts.append(pyr[arrs["heavy_pick"]])
+            parts.append(fold_heavy(acc, arrs, jnp.bitwise_or,
+                                    jnp.zeros((1, w), jnp.uint32),
+                                    fold_steps))
         for i, (k, n) in enumerate(light_meta):
             bt = arrs[f"light{i}_t"]  # [k, n]
             acc = jnp.zeros((n, w), jnp.uint32)
@@ -269,8 +282,12 @@ def _make_core(ell: EllGraph, w: int):
     )
 
     @jax.jit
+    @_obs.scope(f"lanes{32 * w}")
     def core(arrs, fw0, vis0, max_levels):
-        planes0 = tuple(jnp.zeros((act, w), jnp.uint32) for _ in range(8))
+        with _obs.scope("batch.seed"):
+            planes0 = tuple(
+                jnp.zeros((act, w), jnp.uint32) for _ in range(8)
+            )
 
         def cond(carry):
             _, _, _, level, alive = carry
@@ -279,21 +296,29 @@ def _make_core(ell: EllGraph, w: int):
         def body(carry):
             fw, vis, planes, level, _ = carry
             hit = expand(arrs, fw)
-            nxt = hit & ~vis
-            vis2 = vis | nxt
-            # Increment the per-lane level counter wherever the lane is still
-            # unvisited after this level.
-            planes = ripple_increment(planes, ~vis2)
-            fw_next = jnp.concatenate([nxt, jnp.zeros((1, w), jnp.uint32)])
-            alive = jnp.any(nxt != 0)
-            return fw_next, vis2, planes, level + 1, alive
+            with _obs.scope("level.claim"):
+                nxt = hit & ~vis
+                vis2 = vis | nxt
+                # Increment the per-lane level counter wherever the lane
+                # is still unvisited after this level.
+                planes = ripple_increment(planes, ~vis2)
+                fw_next = jnp.concatenate(
+                    [nxt, jnp.zeros((1, w), jnp.uint32)]
+                )
+                alive = jnp.any(nxt != 0)
+                return fw_next, vis2, planes, level + 1, alive
 
-        fw_f, vis_f, planes_f, levels, _ = jax.lax.while_loop(
-            cond, body, (fw0, vis0, planes0, jnp.int32(0), jnp.bool_(True))
-        )
+        # The loop itself (its termination test and carry) is claim work;
+        # the body's gathers name themselves inside it.
+        with _obs.scope("level.claim"):
+            fw_f, vis_f, planes_f, levels, _ = jax.lax.while_loop(
+                cond, body,
+                (fw0, vis0, planes0, jnp.int32(0), jnp.bool_(True)),
+            )
         return planes_f, vis_f, levels
 
     @jax.jit
+    @_obs.scope("batch.stats")
     def extract(planes, vis, src_bits):
         """Unpack bit-sliced counters to per-lane uint8 distances [act, 32w]."""
         shifts = jnp.arange(32, dtype=jnp.uint32)
@@ -423,12 +448,15 @@ class PackedMsBfsEngine(AotProgramProtocol):
         if sources.min() < 0 or sources.max() >= self.ell.num_vertices:
             raise ValueError("source out of range")
         max_levels = min(max_levels, MAX_LEVELS)
-        fw0 = jnp.asarray(self._seed(sources))
-        vis0 = fw0[:-1]
-        t0 = time.perf_counter()
-        planes, vis, levels = self._core(
-            self.arrs, fw0, vis0, jnp.int32(max_levels)
-        )
+        with _obs.host_span("tpu_bfs.engine.dispatch", lambda: {
+            "lanes": self.lanes, "n": len(sources), "max_levels": max_levels,
+        }):
+            fw0 = jnp.asarray(self._seed(sources))
+            vis0 = fw0[:-1]
+            t0 = time.perf_counter()
+            planes, vis, levels = self._core(
+                self.arrs, fw0, vis0, jnp.int32(max_levels)
+            )
         return _PackedPending(
             sources=sources, src_bits=vis0, planes=planes, vis=vis,
             levels=levels, t0=t0,
@@ -441,17 +469,21 @@ class PackedMsBfsEngine(AotProgramProtocol):
         (lane_stats / lane_ecc); the distance table stays bit-sliced on
         device and unpacks lazily on first ``distance_u8`` access, so
         distance-free consumers never pay the O(V * lanes) transfer."""
-        int(pend.levels)  # blocks until the loop finishes
-        elapsed = (time.perf_counter() - pend.t0) if time_it else None
-        self._warmed = True
+        levels = None
+        with _obs.host_span("tpu_bfs.engine.fetch", lambda: {
+            "lanes": self.lanes, "levels": levels,
+        }):
+            levels = int(pend.levels)  # blocks until the loop finishes
+            elapsed = (time.perf_counter() - pend.t0) if time_it else None
+            self._warmed = True
 
-        sources = pend.sources
-        s = len(sources)
-        act = self.ell.num_active
-        r, d = self._lane_stats(pend.vis)
-        e = self._lane_ecc(pend.planes, pend.vis, pend.src_bits)
-        reached = np.asarray(r).reshape(-1)[:s].astype(np.int64)
-        ecc = np.asarray(e).reshape(-1)[:s].astype(np.int32)
+            sources = pend.sources
+            s = len(sources)
+            act = self.ell.num_active
+            r, d = self._lane_stats(pend.vis)
+            e = self._lane_ecc(pend.planes, pend.vis, pend.src_bits)
+            reached = np.asarray(r).reshape(-1)[:s].astype(np.int64)
+            ecc = np.asarray(e).reshape(-1)[:s].astype(np.int32)
         slot_sum = (
             np.asarray(d).astype(np.int64).sum(axis=1).reshape(-1)[:s]
         )

@@ -304,8 +304,8 @@ def _run_multi_source(args, g, golden, on_result=None) -> int:
         )
     from tpu_bfs import obs as obs_mod
 
-    with obs_mod.maybe_span("engine_build", "cli", cat="cli",
-                            lanes=args.lanes, engine=args.engine):
+    with obs_mod.host_span("tpu_bfs.setup.engine_build", span_id="cli",
+                           cat="cli", lanes=args.lanes, engine=args.engine):
         engine = _make_ms_engine(args, g, len(sources))
     aot_store = aot_spec = None
     if args.aot:
@@ -722,7 +722,8 @@ def main(argv=None, *, on_result=None) -> int:
     t0 = time.perf_counter()
     from tpu_bfs import obs as obs_mod
 
-    with obs_mod.maybe_span("graph_load", "cli", cat="cli", graph=args.graph):
+    with obs_mod.host_span("tpu_bfs.setup.graph_load", span_id="cli",
+                           cat="cli", graph=args.graph):
         g = load_graph(args.graph)
     print(f"Number of vertices {g.num_vertices}")  # reference prints these (bfs.cu:789-790)
     print(f"Number of edges {g.num_edges}")
@@ -797,8 +798,9 @@ def main(argv=None, *, on_result=None) -> int:
             return TiledBfsEngine(g, pull_gate=args.pull_gate)
         return BfsEngine(g, backend=args.backend)
 
-    with obs_mod.maybe_span("engine_build", "cli", cat="cli",
-                            backend=args.backend, devices=args.devices):
+    with obs_mod.host_span("tpu_bfs.setup.engine_build", span_id="cli",
+                           cat="cli", backend=args.backend,
+                           devices=args.devices):
         engine = make_engine()
 
     if args.ckpt or args.resume:

@@ -434,8 +434,8 @@ class DynamicGraph:
         with self._lock:
             gen_id = store.next_generation_id()
             g = self.materialize()
-            with _obs.maybe_span("compact", f"gen{gen_id}",
-                                 cat="graph.dynamic", generation=gen_id):
+            with _obs.host_span("tpu_bfs.graph.compact", span_id=f"gen{gen_id}",
+                                cat="graph.dynamic", generation=gen_id):
                 path = store.save(gen_id, g)
                 if _faults.ACTIVE is not None:
                     # Chaos site (ISSUE 19): compaction_crash raises

@@ -32,6 +32,25 @@ What the armed recorder collects:
   (``/metricz`` via ``BfsService.metricz`` and ``--metricz-out``), and
   plain JSONL.
 
+Names in the profiler's own trace (``jax.profiler``: the CLI's
+``--profile-dir``, serve's ``--xprof-dir``), the same trace the device's
+ops land in:
+
+- :class:`scope` names a phase of a jitted program (a
+  ``jax.named_scope``, as a ``with`` block or a decorator): it lands in
+  each compiled op's ``op_name`` metadata, and only there
+  (tests/test_obs_scopes.py holds the compiled programs equal with and
+  without it). The level loop's phases are
+  ``level.tiles``, ``level.gather``, ``level.heavy``, ``level.push`` and
+  ``level.claim``; a batch's set-up and reductions ``batch.seed`` and
+  ``batch.stats``; an engine's whole core ``lanes<W>``.
+- :func:`host_span` names one host step per batch (``tpu_bfs.engine.*``,
+  ``tpu_bfs.serve.*``), or one set-up step (``tpu_bfs.setup.*``,
+  ``tpu_bfs.graph.compact``), as a ``jax.profiler.TraceAnnotation`` on
+  the profiler's clock, with stats computed only while a profiler
+  records; where a recorder is armed the same call records its
+  begin/end pair. With neither on it returns a shared no-op span.
+
 Spec grammar (``--obs`` / ``TPU_BFS_OBS``)::
 
     spec  := "1" | "true" | "0" | "off" | kv ("," kv)*
@@ -61,7 +80,8 @@ __all__ = [
     "arm_from_spec",
     "arm_from_spec_or_env",
     "disarm",
-    "maybe_span",
+    "host_span",
+    "scope",
 ]
 
 # THE guard production sites check: None (the default) keeps every
@@ -148,18 +168,130 @@ def arm_for_run(spec: str | None, trace_out: str | None = None,
     return rec
 
 
-def maybe_span(name: str, span_id: str, *, cat: str = "span", **args):
-    """``ACTIVE.span(...)`` when armed, a no-op context otherwise — for
-    COLD paths (graph load, engine build/warm) where the armed/disarmed
-    fork would otherwise be written out twice. Hot loops keep the
-    explicit ``if obs.ACTIVE is not None`` guard: one attribute read,
-    no context-manager allocation (tests/test_obs.py pins that)."""
-    rec = ACTIVE
-    if rec is None:
-        return contextlib.nullcontext()
-    return rec.span(name, span_id, cat=cat, **args)
-
-
 def disarm() -> None:
     global ACTIVE
     ACTIVE = None
+
+
+class scope(contextlib.ContextDecorator):
+    """``jax.named_scope(name)``, for use inside jitted code, as a
+    ``with`` block or a decorator: the phase name reaches each compiled
+    op's ``op_name`` metadata (the trace's ``tf_op``) and changes nothing
+    else of the program. Each use opens a fresh named scope, so one
+    decorated function may be traced from several threads at once."""
+
+    __slots__ = ("name", "_cm")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def _recreate_cm(self):
+        return scope(self.name)
+
+    def __enter__(self):
+        import jax
+
+        self._cm = jax.named_scope(self.name)
+        return self._cm.__enter__()
+
+    def __exit__(self, *exc):
+        return self._cm.__exit__(*exc)
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _profiler_annotation():
+    """The annotation class while a profiler records, else None."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        from jax.profiler import TraceAnnotation as ann
+
+        _annotation = ann
+    return ann if ann.is_enabled() else None
+
+
+class _NullSpan:
+    """What :func:`host_span` returns with no profiler and no recorder."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        """End arguments for the recorder's span: none to keep here."""
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _HostSpan:
+    __slots__ = ("_ann", "_stats", "_rec", "_name", "_id", "_cat", "_args",
+                 "_end")
+
+    def __init__(self, ann, stats, rec, name, span_id, cat, args):
+        self._ann = ann
+        self._stats = stats
+        self._rec = rec
+        self._name = name
+        self._id = span_id
+        self._cat = cat
+        self._args = args
+        self._end = {}
+
+    def __enter__(self):
+        if self._rec is not None:
+            self._rec.begin(self._name.rsplit(".", 1)[-1], self._id,
+                            cat=self._cat, **self._args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def set(self, **args) -> None:
+        """End arguments for the recorder's span (its outcome)."""
+        self._end.update(args)
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            if self._stats is not None and exc_type is None:
+                self._ann.set_metadata(**self._stats())
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._rec is not None:
+            if exc_type is not None:
+                self._end.setdefault("failed", True)
+            self._rec.end(self._name.rsplit(".", 1)[-1], self._id,
+                          cat=self._cat, **self._end)
+        return False
+
+
+def host_span(name: str, stats=None, *, span_id: str | None = None,
+              cat: str = "span", **args):
+    """One host step (a batch's, or a set-up step) as a span.
+
+    - While a profiler records: a ``jax.profiler.TraceAnnotation`` named
+      ``name`` (``tpu_bfs.<layer>.<step>``), on the profiler's clock in
+      the same trace as the device's ops. ``stats`` is a callable
+      returning its keyword stats; it is called once, as the span ends
+      without an exception, so it may read what the step found, and only
+      while a profiler records.
+    - Where a recorder is armed and ``span_id`` is given: the recorder's
+      begin/end pair over the same interval, named by the last part of
+      ``name`` (``tpu_bfs.serve.dispatch`` -> ``dispatch``), begun with
+      ``args`` and ended with what :meth:`set` was given (plus
+      ``failed=True`` when an exception leaves the span).
+
+    With neither on it returns one shared no-op span: the caller pays an
+    ``is_enabled`` check and an attribute read, and ``stats`` is never
+    called."""
+    ann = _profiler_annotation()
+    rec = ACTIVE if span_id is not None else None
+    if ann is None and rec is None:
+        return _NULL_SPAN
+    return _HostSpan(None if ann is None else ann(name),
+                     None if ann is None else stats, rec, name, span_id, cat,
+                     args)

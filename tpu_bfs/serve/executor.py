@@ -383,6 +383,7 @@ class BatchExecutor:
             )
         pending = PendingBatch(engine, queries, n, padded, kind, params)
         rec = _obs.ACTIVE
+        mesh_kw = {"devices": pending.devices} if pending.devices > 1 else {}
         if rec is not None:
             # The batch span opens at dispatch and closes when every
             # query resolved (finish) or the batch failed; every query's
@@ -393,53 +394,52 @@ class BatchExecutor:
             for q in pending.queries:
                 if hasattr(q, "obs_batch"):
                     q.obs_batch = pending.bid
-            mesh_kw = (
-                {"devices": pending.devices} if pending.devices > 1 else {}
-            )
-            rec.begin("batch", f"b{pending.bid}",  # span-outlives: finish_batch/_extract/_classify_failure close it
+            rec.begin("batch", f"b{pending.bid}",  # span-outlives: finish_batch/_resolve_ok/_classify_failure close it
                       cat="serve.batch",
                       batch=pending.bid, n=n, width=pending.lanes,
                       queries=[q.id for q in pending.queries], **mesh_kw)
-            rec.begin("dispatch", f"b{pending.bid}", cat="serve.batch",
-                      batch=pending.bid, width=pending.lanes, **mesh_kw)
-        while True:
-            try:
-                if _faults.ACTIVE is not None:
-                    # Chaos-harness injection site: engine-agnostic (the
-                    # _packed_common dispatch/fetch sites cover real
-                    # engines; this one also covers test doubles).
-                    _faults.ACTIVE.hit("serve_batch", lanes=pending.lanes,
-                                       n=pending.n)
-                pending.t_dispatch = time.monotonic()
-                pending.handle = self._dispatch(engine, padded,
-                                                pending.params)
-                if rec is not None:
-                    rec.end("dispatch", f"b{pending.bid}", cat="serve.batch",
-                            batch=pending.bid, attempt=pending.attempt)
-                return pending
-            except Exception as exc:  # noqa: BLE001 — gated by the classifier
+        # The admission-to-dispatch wait, summed over the batch's queries
+        # from stamps already taken (``now`` above, each ``t_submit``),
+        # is computed only while a profiler records.
+        with _obs.host_span("tpu_bfs.serve.dispatch", lambda: {
+            "width": pending.lanes, "n": n,
+            "wait_ms_sum": 1e3 * sum(now - q.t_submit for q in queries),
+        }, span_id=f"b{pending.bid}", cat="serve.batch", batch=pending.bid,
+                width=pending.lanes, **mesh_kw) as span:
+            while True:
                 try:
-                    retry = self._classify_failure(pending, exc)
-                except BatchRequeue as brq:
-                    # The OOM/mesh-fault rides up to the service's
-                    # requeue ladder; the open dispatch span must not
-                    # dangle in the trace (the classifier already ended
-                    # the batch span).
-                    if rec is not None:
-                        rec.end("dispatch", f"b{pending.bid}",
-                                cat="serve.batch", batch=pending.bid,
-                                **({"oom": True}
-                                   if isinstance(brq, OomRequeue)
-                                   else {"mesh_fault": True}))
-                    raise
-                if not retry:
-                    if rec is not None:
-                        rec.end("dispatch", f"b{pending.bid}",
-                                cat="serve.batch", batch=pending.bid,
-                                failed=True)
-                        rec.end("batch", f"b{pending.bid}", cat="serve.batch",
-                                batch=pending.bid, failed=True)
-                    return None
+                    if _faults.ACTIVE is not None:
+                        # Chaos-harness injection site: engine-agnostic
+                        # (the _packed_common dispatch/fetch sites cover
+                        # real engines; this one also covers test
+                        # doubles).
+                        _faults.ACTIVE.hit("serve_batch",
+                                           lanes=pending.lanes, n=pending.n)
+                    pending.t_dispatch = time.monotonic()
+                    pending.handle = self._dispatch(engine, padded,
+                                                    pending.params)
+                    span.set(batch=pending.bid, attempt=pending.attempt)
+                    return pending
+                except Exception as exc:  # noqa: BLE001 — gated by the classifier
+                    try:
+                        retry = self._classify_failure(pending, exc)
+                    except BatchRequeue as brq:
+                        # The OOM/mesh-fault rides up to the service's
+                        # requeue ladder (the classifier already ended
+                        # the batch span; the dispatch span ends as it
+                        # leaves).
+                        span.set(batch=pending.bid,
+                                 **({"oom": True}
+                                    if isinstance(brq, OomRequeue)
+                                    else {"mesh_fault": True}))
+                        raise
+                    if not retry:
+                        span.set(batch=pending.bid, failed=True)
+                        break
+        if rec is not None:
+            rec.end("batch", f"b{pending.bid}", cat="serve.batch",
+                    batch=pending.bid, failed=True)
+        return None
 
     def finish_batch(self, pending: PendingBatch) -> None:
         """Block on a dispatched batch and resolve every query exactly
@@ -447,69 +447,72 @@ class BatchExecutor:
         (the handle is dead once its fetch raised); OOM raises
         :class:`OomRequeue` exactly as the dispatch half does."""
         engine = pending.engine
-        rec = _obs.ACTIVE
-        if rec is not None:
-            rec.begin("fetch", f"b{pending.bid}", cat="serve.batch",
-                      batch=pending.bid, n=pending.n)
-        while True:
-            try:
-                if pending.handle is None:  # re-dispatch after a retry
-                    pending.t_dispatch = time.monotonic()
-                    pending.handle = self._dispatch(
-                        engine, pending.padded, pending.params
-                    )
-                res = self._fetch_watched(engine, pending)
-                # The batch's device occupancy — the per-query GTEPS
-                # denominator. Under pipelining, dispatch time includes
-                # the wait behind the previous in-flight batch (one
-                # device stream), so the window is clamped to start no
-                # earlier than the previous batch's fetch-return on this
-                # engine: an approximation of the true compute window
-                # (slightly late on the start side), but it no longer
-                # double-counts the predecessor's whole runtime.
-                t_done = time.monotonic()
-                if pending.t_dispatch is not None:
-                    start = pending.t_dispatch
-                    prev_done = engine.__dict__.get("_serve_prev_fetch_done")
-                    if prev_done is not None and prev_done > start:
-                        start = prev_done
-                    pending.device_ms = (t_done - start) * 1e3
-                engine.__dict__["_serve_prev_fetch_done"] = t_done
-                # Modeled exchange bytes: the READY-only reader — fetch
-                # of batch N must not block on (or wait for) batch N+1's
-                # still-running loop. See completed_exchange_record for
-                # the bounded adjacent-batch attribution caveat.
-                taker = getattr(engine, "completed_exchange_record", None)
-                wb = (
-                    taker()[1] if taker is not None
-                    else getattr(engine, "last_exchange_bytes", None)
-                )
-                pending.wire_bytes = None if wb is None else float(wb)
-                break
-            except Exception as exc:  # noqa: BLE001 — gated by the classifier
-                pending.handle = None
+        with _obs.host_span("tpu_bfs.serve.fetch", lambda: {
+            "width": pending.lanes, "n": pending.n,
+        }, span_id=f"b{pending.bid}", cat="serve.batch", batch=pending.bid,
+                n=pending.n) as span:
+            while True:
                 try:
-                    retry = self._classify_failure(pending, exc)
-                except BatchRequeue as brq:
-                    # Same discipline as the dispatch half: close the
-                    # open fetch span before the OOM/mesh-fault rides up.
-                    if rec is not None:
-                        rec.end("fetch", f"b{pending.bid}", cat="serve.batch",
-                                batch=pending.bid,
-                                **({"oom": True}
-                                   if isinstance(brq, OomRequeue)
-                                   else {"mesh_fault": True}))
-                    raise
-                if not retry:
-                    if rec is not None:
-                        rec.end("fetch", f"b{pending.bid}", cat="serve.batch",
-                                batch=pending.bid, failed=True)
-                        rec.end("batch", f"b{pending.bid}", cat="serve.batch",
-                                batch=pending.bid, failed=True)
-                    return
-        if rec is not None:
-            rec.end("fetch", f"b{pending.bid}", cat="serve.batch",
-                    batch=pending.bid, attempt=pending.attempt)
+                    if pending.handle is None:  # re-dispatch after a retry
+                        pending.t_dispatch = time.monotonic()
+                        pending.handle = self._dispatch(
+                            engine, pending.padded, pending.params
+                        )
+                    res = self._fetch_watched(engine, pending)
+                    # The batch's device occupancy — the per-query GTEPS
+                    # denominator. Under pipelining, dispatch time
+                    # includes the wait behind the previous in-flight
+                    # batch (one device stream), so the window is clamped
+                    # to start no earlier than the previous batch's
+                    # fetch-return on this engine: an approximation of
+                    # the true compute window (slightly late on the start
+                    # side), but it no longer double-counts the
+                    # predecessor's whole runtime.
+                    t_done = time.monotonic()
+                    if pending.t_dispatch is not None:
+                        start = pending.t_dispatch
+                        prev_done = engine.__dict__.get(
+                            "_serve_prev_fetch_done")
+                        if prev_done is not None and prev_done > start:
+                            start = prev_done
+                        pending.device_ms = (t_done - start) * 1e3
+                    engine.__dict__["_serve_prev_fetch_done"] = t_done
+                    # Modeled exchange bytes: the READY-only reader —
+                    # fetch of batch N must not block on (or wait for)
+                    # batch N+1's still-running loop. See
+                    # completed_exchange_record for the bounded
+                    # adjacent-batch attribution caveat.
+                    taker = getattr(engine, "completed_exchange_record",
+                                    None)
+                    wb = (
+                        taker()[1] if taker is not None
+                        else getattr(engine, "last_exchange_bytes", None)
+                    )
+                    pending.wire_bytes = None if wb is None else float(wb)
+                    span.set(batch=pending.bid, attempt=pending.attempt)
+                    break
+                except Exception as exc:  # noqa: BLE001 — gated by the classifier
+                    pending.handle = None
+                    try:
+                        retry = self._classify_failure(pending, exc)
+                    except BatchRequeue as brq:
+                        # Same discipline as the dispatch half: the fetch
+                        # span ends as the OOM/mesh-fault rides up.
+                        span.set(batch=pending.bid,
+                                 **({"oom": True}
+                                    if isinstance(brq, OomRequeue)
+                                    else {"mesh_fault": True}))
+                        raise
+                    if not retry:
+                        span.set(batch=pending.bid, failed=True)
+                        res = None
+                        break
+        if res is None:
+            rec = _obs.ACTIVE
+            if rec is not None:
+                rec.end("batch", f"b{pending.bid}", cat="serve.batch",
+                        batch=pending.bid, failed=True)
+            return
         # The result now owns whatever device state extraction needs; drop
         # the handle's copy so the batch's loop outputs free as soon as
         # the result does.
@@ -719,28 +722,31 @@ class BatchExecutor:
                 breaker_key(pending.lanes, pending.devices,
                             pending.kind)
             )
-        rec = _obs.ACTIVE
-        if rec is not None:
-            rec.begin("extract", f"b{pending.bid}",  # span-outlives: _extract ends it; the except arm below covers the failure path
-                      cat="serve.batch",
-                      batch=pending.bid, n=pending.n)
         try:
-            self._extract(pending, res, rec)
+            with _obs.host_span("tpu_bfs.serve.extract", lambda: {
+                "width": pending.lanes, "n": pending.n,
+            }, span_id=f"b{pending.bid}", cat="serve.batch",
+                    batch=pending.bid, n=pending.n) as span:
+                self._extract(pending, res, span)
         except Exception:
             # An extraction failure propagates to the service's catch-all
-            # (which flight-dumps it); the open extract/batch spans must
-            # not dangle in the very trace written for that incident.
+            # (which flight-dumps it); the open batch span must not
+            # dangle in the very trace written for that incident (the
+            # extract span ended failed as the exception left it).
+            rec = _obs.ACTIVE
             if rec is not None:
-                rec.end("extract", f"b{pending.bid}", cat="serve.batch",
-                        batch=pending.bid, failed=True)
                 rec.end("batch", f"b{pending.bid}", cat="serve.batch",
                         batch=pending.bid, failed=True)
             raise
+        rec = _obs.ACTIVE
+        if rec is not None:
+            rec.end("batch", f"b{pending.bid}", cat="serve.batch",
+                    batch=pending.bid, n=pending.n, width=pending.lanes)
 
-    def _extract(self, pending: PendingBatch, res, rec) -> None:
+    def _extract(self, pending: PendingBatch, res, span) -> None:
         from tpu_bfs.graph.csr import INF_DIST
 
-        engine, queries, n = pending.engine, pending.queries, pending.n
+        queries, n = pending.queries, pending.n
         # Ladder units (ladder_lanes where the adapter's capacity
         # differs): the width responses/metrics report must match the
         # routing histogram's rungs.
@@ -768,57 +774,63 @@ class BatchExecutor:
         )
         t_x0 = time.monotonic()
         latencies = []
-        for i, q in enumerate(queries):
-            want = getattr(q, "want_distances", True)
-            d = None
-            if want or ecc is None:
-                # The one per-lane device->host distance pull. Metadata-only
-                # queries skip it entirely when the engine reduced the
-                # summaries on device (ecc — every packed engine does).
-                d = res.distances_int32(i)
-            if ecc is not None:
-                levels = int(ecc[i])
-            else:
-                finite = d[d != INF_DIST]
-                levels = int(finite.max()) if finite.size else 0
-            extras_i = extras_fn(i) if extras_fn is not None else None
-            reached_i = int(res.reached[i])
-            if _faults.ACTIVE is not None:
-                # Chaos hook (ISSUE 15): corrupt_result rules flip one
-                # bit of THIS query's just-extracted answer — the
-                # client-visible corruption every integrity detector
-                # must catch (red-before-green for the audit tier).
-                d, extras_i, reached_i, _fired = _faults.maybe_corrupt_result(
-                    d, extras_i, reached_i, lanes=width, batch=pending.bid,
-                )
-            # Stamp at RESOLVE time, per query: extraction cost is real
-            # client-visible latency (the old shared pre-extraction stamp
-            # hid it, and hid the pipelining win with it).
-            latency_ms = (time.monotonic() - q.t_submit) * 1e3
-            q.resolve(QueryResult(
-                id=q.id,
-                source=q.source,
-                status=STATUS_OK,
-                kind=pending.kind,
-                extras=extras_i,
-                distances=d if want else None,
-                levels=levels,
-                reached=reached_i,
-                latency_ms=latency_ms,
-                batch_lanes=n,
-                dispatched_lanes=width,
-                devices=pending.devices,
-                edges=(
-                    int(edges_arr[i]) if edges_arr is not None else None
-                ),
-                device_ms=pending.device_ms,
-                wire_bytes=wire_share,
-            ))
-            latencies.append(latency_ms)
+        # Resolving a query runs its completion callbacks, where the
+        # frontend encodes and writes its response line: this loop is the
+        # batch's response write (with the distance row pulls of the
+        # queries that want rows).
+        with _obs.host_span("tpu_bfs.serve.write", lambda: {"n": n}):
+            for i, q in enumerate(queries):
+                want = getattr(q, "want_distances", True)
+                d = None
+                if want or ecc is None:
+                    # The one per-lane device->host distance pull.
+                    # Metadata-only queries skip it entirely when the
+                    # engine reduced the summaries on device (ecc — every
+                    # packed engine does).
+                    d = res.distances_int32(i)
+                if ecc is not None:
+                    levels = int(ecc[i])
+                else:
+                    finite = d[d != INF_DIST]
+                    levels = int(finite.max()) if finite.size else 0
+                extras_i = extras_fn(i) if extras_fn is not None else None
+                reached_i = int(res.reached[i])
+                if _faults.ACTIVE is not None:
+                    # Chaos hook: corrupt_result rules flip one
+                    # bit of THIS query's just-extracted answer — the
+                    # client-visible corruption every integrity detector
+                    # must catch (red-before-green for the audit tier).
+                    d, extras_i, reached_i, _fired = (
+                        _faults.maybe_corrupt_result(
+                            d, extras_i, reached_i, lanes=width,
+                            batch=pending.bid,
+                        )
+                    )
+                # Stamp at RESOLVE time, per query: extraction cost is
+                # real client-visible latency (the old shared
+                # pre-extraction stamp hid it, and hid the pipelining win
+                # with it).
+                latency_ms = (time.monotonic() - q.t_submit) * 1e3
+                q.resolve(QueryResult(
+                    id=q.id,
+                    source=q.source,
+                    status=STATUS_OK,
+                    kind=pending.kind,
+                    extras=extras_i,
+                    distances=d if want else None,
+                    levels=levels,
+                    reached=reached_i,
+                    latency_ms=latency_ms,
+                    batch_lanes=n,
+                    dispatched_lanes=width,
+                    devices=pending.devices,
+                    edges=(
+                        int(edges_arr[i]) if edges_arr is not None else None
+                    ),
+                    device_ms=pending.device_ms,
+                    wire_bytes=wire_share,
+                ))
+                latencies.append(latency_ms)
         extract_ms = (time.monotonic() - t_x0) * 1e3
-        if rec is not None:
-            rec.end("extract", f"b{pending.bid}", cat="serve.batch",
-                    batch=pending.bid, extract_ms=round(extract_ms, 3))
-            rec.end("batch", f"b{pending.bid}", cat="serve.batch",
-                    batch=pending.bid, n=n, width=width)
+        span.set(batch=pending.bid, extract_ms=round(extract_ms, 3))
         self.metrics.record_batch(n, width, latencies, extract_ms=extract_ms)

@@ -559,9 +559,9 @@ class EngineRegistry:
         sources, so this warm run compiles THE shape every later dispatch
         reuses. Vertex 0 always exists; its answer is discarded."""
         t0 = time.perf_counter()
-        with _obs.maybe_span("engine_warm", f"w{spec.lanes}",
-                             cat="serve.registry", width=spec.lanes,
-                             engine=spec.engine):
+        with _obs.host_span("tpu_bfs.setup.engine_warm", span_id=f"w{spec.lanes}",
+                            cat="serve.registry", width=spec.lanes,
+                            engine=spec.engine):
             eng.run(np.zeros(eng.lanes, dtype=np.int64), time_it=False)
             # Residency warm-up hook (ISSUE 15 satellite / ROADMAP 3b):
             # engines with per-residency caches beyond the compiled

@@ -354,7 +354,11 @@ class AdmissionQueue:
         returning early the instant it fills. After ``stop()`` the
         remaining queries drain immediately (no linger, no kind filter —
         the caller only resolves them as SHUTDOWN); returns [] only when
-        stopped AND empty."""
+        stopped AND empty.
+
+        The forming of a batch, from the moment work exists, is the host
+        span ``tpu_bfs.serve.coalesce`` (tpu_bfs/obs): pending queries at
+        its start, queries taken, and milliseconds it lasted."""
         with self._cond:
             while not self._items and not self._stopped:
                 self._cond.wait()
@@ -365,31 +369,38 @@ class AdmissionQueue:
                     self._bump(self._key_of(q), -1)
                     taken.append(q)
                 return taken
-            key = self._key_of(self._items[0])
-            if linger_s > 0:
-                deadline = time.monotonic() + linger_s
-                while (self._key_counts.get(key, 0) < max_n
-                       and not self._stopped):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(remaining)
-            if len(self._key_counts) == 1:
-                # Single-kind traffic: the original O(batch) popleft path.
-                n = min(max_n, len(self._items))
-                taken = [self._items.popleft() for _ in range(n)]
-                self._bump(key, -n)
+            t0 = time.monotonic()
+            pending = len(self._items)
+            with _obs.host_span("tpu_bfs.serve.coalesce", lambda: {
+                "pending": pending, "taken": len(taken),
+                "lingered_ms": (time.monotonic() - t0) * 1e3,
+            }):
+                key = self._key_of(self._items[0])
+                if linger_s > 0:
+                    deadline = t0 + linger_s
+                    while (self._key_counts.get(key, 0) < max_n
+                           and not self._stopped):
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            break
+                        self._cond.wait(remaining)
+                if len(self._key_counts) == 1:
+                    # Single-kind traffic: the original O(batch) popleft
+                    # path.
+                    n = min(max_n, len(self._items))
+                    taken = [self._items.popleft() for _ in range(n)]
+                    self._bump(key, -n)
+                    return taken
+                taken = []
+                rest: deque = deque()
+                for q in self._items:
+                    if len(taken) < max_n and self._key_of(q) == key:
+                        taken.append(q)
+                    else:
+                        rest.append(q)
+                self._items = rest
+                self._bump(key, -len(taken))
                 return taken
-            taken = []
-            rest: deque = deque()
-            for q in self._items:
-                if len(taken) < max_n and self._key_of(q) == key:
-                    taken.append(q)
-                else:
-                    rest.append(q)
-            self._items = rest
-            self._bump(key, -len(taken))
-            return taken
 
     def stop(self) -> None:
         with self._cond:

@@ -485,8 +485,9 @@ def export_engine_programs(engine, spec, store: ArtifactStore, *,
         # Re-exporting from an adopted engine must serialize the
         # original traceable, not the wrapper.
         fn = getattr(fn, "_aot_original", fn)
-        with _obs.maybe_span(
-            "aot_export", f"{key['engine']}-w{key['lanes']}-{name}",
+        with _obs.host_span(
+            "tpu_bfs.setup.aot_export",
+            span_id=f"{key['engine']}-w{key['lanes']}-{name}",
             cat="aot", program=name, width=key["lanes"],
         ):
             try:
@@ -518,8 +519,9 @@ def adopt_engine_programs(engine, spec, store: ArtifactStore, *,
     key = program_key(spec)
     programs = {}
     for name, _attr, fn, _args in engine.export_programs():
-        with _obs.maybe_span(
-            "aot_load", f"{key['engine']}-w{key['lanes']}-{name}",
+        with _obs.host_span(
+            "tpu_bfs.setup.aot_load",
+            span_id=f"{key['engine']}-w{key['lanes']}-{name}",
             cat="aot", program=name, width=key["lanes"],
         ):
             got = store.get(key, name, with_meta=True)

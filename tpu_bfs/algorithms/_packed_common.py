@@ -1184,6 +1184,9 @@ class PackedBatchResult:
     # Lanes whose source is an isolated vertex (no table row; traversal is
     # trivially {source}); None when the engine's tables cover all vertices.
     _iso: np.ndarray | None = None
+    # lane_ecc's [w, 32] device output when it was launched at dispatch
+    # (PackedRunProtocol.launch_summaries); None computes it on first read.
+    _ecc_dev: object = None
     _ecc_cache: np.ndarray | None = None
     _word_cache: dict = dataclasses.field(default_factory=dict)
     _parent_cache: dict = dataclasses.field(default_factory=dict)
@@ -1208,14 +1211,18 @@ class PackedBatchResult:
         distance words host-side, so distance-free consumers (the serve
         path's want_distances=false) answer ``levels`` without ever
         pulling a distance row. Lazy: one-shot callers that never read it
-        never pay the kernel. None when the engine predates the kernel."""
+        never pay the kernel, unless the serve path launched it at dispatch
+        (``_ecc_dev``). None when the engine predates the kernel."""
         if self._ecc_cache is None:
-            lane_ecc = getattr(self._engine, "_lane_ecc", None)
-            if lane_ecc is None:
-                return None
+            e_dev = self._ecc_dev
+            if e_dev is None:
+                lane_ecc = getattr(self._engine, "_lane_ecc", None)
+                if lane_ecc is None:
+                    return None
+                e_dev = lane_ecc(self._planes, self._vis, self._src_bits)
             eng = self._engine
             e = eng._lane_order(
-                np.asarray(lane_ecc(self._planes, self._vis, self._src_bits))
+                np.asarray(e_dev)
             )[: len(self.sources)].astype(np.int32)
             if self._iso is not None:
                 # Isolated sources never touch the device; their component
@@ -1809,16 +1816,18 @@ def advance_packed_batch(engine, ckpt, levels: int | None = None):
 
 def _assemble_packed_result(
     engine, sources, planes, vis, src_bits_raw, levels, alive, elapsed,
-    iso_override=None,
+    iso_override=None, stats=None, ecc=None,
 ) -> PackedBatchResult:
     """Result assembly shared by run_packed_batch and finish_packed_batch:
     device-side lane stats, isolated-lane patching, sentinel-row src-bits
     view, and the final-empty-frontier level adjustment. ``iso_override``
     (from a checkpoint's persisted mask) wins over the engine's own
     isolated-lane reckoning — the finishing engine may not be able to
-    reconstruct it (prebuilt directed shard sets)."""
+    reconstruct it (prebuilt directed shard sets). ``stats`` / ``ecc``
+    are lane_stats' and lane_ecc's outputs where they were launched at
+    dispatch (launch_summaries); lane_stats runs here otherwise."""
     s = len(sources)
-    r, d = engine._lane_stats(vis)
+    r, d = engine._lane_stats(vis) if stats is None else stats
     reached = engine._lane_order(np.asarray(r))[:s].astype(np.int64)
     # d is [w, nblocks, 32] int32 block partials; the int64 block reduction
     # happens here on host, so edges_traversed is exact at any scale.
@@ -1853,6 +1862,7 @@ def _assemble_packed_result(
         _vis=vis,
         _src_bits=src_bits,
         _iso=iso,
+        _ecc_dev=ecc,
     )
     # The loop's last body found an empty frontier iff not alive; then the
     # max eccentricity is one less than the body count.
@@ -1901,6 +1911,11 @@ class PackedDispatch:
     truncated: object
     max_levels: int
     t0: float
+    # The answer reductions launched right behind the core
+    # (PackedRunProtocol.launch_summaries): lane_stats' (reached,
+    # deg_sum) and lane_ecc's output, device arrays; None until launched.
+    stats: tuple | None = None
+    ecc: object = None
 
 
 def _engine_dispatch_lock(engine):
@@ -1990,7 +2005,7 @@ def fetch_packed_batch(
             )
         result = _assemble_packed_result(
             engine, pend.sources, pend.planes, pend.vis, pend.fw0, levels,
-            bool(pend.alive), elapsed
+            bool(pend.alive), elapsed, stats=pend.stats, ecc=pend.ecc,
         )
     if _obs.ACTIVE is not None:
         # Engine-trace assembly (tpu_bfs/obs/engine_trace) reads the gate
@@ -2115,3 +2130,17 @@ class PackedRunProtocol:
     def fetch(self, pend, *, check_cap=True):
         """Block on a :meth:`dispatch` handle and assemble its result."""
         return fetch_packed_batch(self, pend, check_cap=check_cap)
+
+    def launch_summaries(self, pend, *, ecc=True):
+        """Enqueue a dispatched batch's answer reductions (lane_stats, and
+        lane_ecc when ``ecc``) on its own outputs, right behind its core,
+        keeping the device arrays on ``pend`` for :meth:`fetch` and the
+        result's ``ecc`` to read. The same kernels on the same arrays as
+        at fetch; only their place in the device's queue moves, so a
+        batch's answers do not wait behind cores dispatched after it. The
+        serve executor calls this; :meth:`run` never does."""
+        pend.stats = self._lane_stats(pend.vis)
+        lane_ecc = getattr(self, "_lane_ecc", None)
+        if ecc and lane_ecc is not None:
+            src_bits = getattr(self, "_src_bits_view", lambda x: x)(pend.fw0)
+            pend.ecc = lane_ecc(pend.planes, pend.vis, src_bits)

@@ -120,7 +120,7 @@ _COUNTER_KEYS = frozenset((
     "completed", "batches", "rejected", "expired", "errors", "shutdown",
     "retries", "oom_degrades", "requeued", "watchdog_trips",
     "requeue_shed", "padded_lanes_total", "breaker_opens",
-    "lanes_used", "lanes_offered",
+    "lanes_used", "lanes_offered", "batches_held",
     "mesh_faults", "mesh_degrades", "query_resumes", "resume_snapshots",
     "audits_run", "audit_failures", "audit_errors", "audit_dropped",
     "quarantines",

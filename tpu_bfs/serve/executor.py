@@ -245,7 +245,8 @@ class PendingBatch:
     __slots__ = ("engine", "queries", "n", "padded", "handle", "attempt",
                  "lanes", "bid", "devices", "t_dispatch", "device_ms",
                  "wire_bytes", "kind", "params", "generation",
-                 "overlay_epoch")
+                 "overlay_epoch", "core_ready", "_on_core_ready",
+                 "_core_lock")
 
     def __init__(self, engine, queries, n: int, padded: np.ndarray,
                  kind: str = "bfs", params: dict | None = None):
@@ -293,6 +294,57 @@ class PendingBatch:
         # compactions), so the shadow auditor can tell "replayable
         # against the live tables" from "superseded install".
         self.overlay_epoch = 0
+        # Set once this batch's core no longer occupies the device: its
+        # outputs are ready, it failed, or its fetch half gave it up
+        # (watch_core). The scheduler holds partial batches while any
+        # dispatched core is unfinished.
+        self.core_ready = threading.Event()
+        self._core_lock = threading.Lock()
+        self._on_core_ready = None  # guarded-by: _core_lock
+
+    def watch_core(self, on_ready) -> None:
+        """Call ``on_ready()`` once, when this batch's core has finished
+        on the device. Observed, not guessed: a daemon thread blocks in
+        the handle's ``levels.block_until_ready()`` (the level loop's
+        last output). A handle with no such device value (host values,
+        test doubles), or whose value is ready already, counts as
+        finished now. :meth:`mark_core_ready` also fires it, so a core
+        that never reports back (a watchdog trip) holds nothing past its
+        batch's fetch half."""
+        with self._core_lock:
+            if not self.core_ready.is_set():
+                self._on_core_ready = on_ready
+                on_ready = None
+        if on_ready is not None:
+            on_ready()
+            return
+        levels = getattr(self.handle, "levels", None)
+        ready = getattr(levels, "block_until_ready", None)
+        is_ready = getattr(levels, "is_ready", None)
+        if ready is None or (is_ready is not None and is_ready()):
+            self.mark_core_ready()
+            return
+
+        def wait():
+            try:
+                ready()
+            except Exception:  # noqa: BLE001 — the fetch half classifies it
+                pass
+            finally:
+                self.mark_core_ready()
+
+        threading.Thread(target=wait, name="bfs-serve-core-wait",
+                         daemon=True).start()
+
+    def mark_core_ready(self) -> None:
+        """Set :attr:`core_ready` and fire the watcher, once."""
+        with self._core_lock:
+            if self.core_ready.is_set():
+                return
+            self.core_ready.set()
+            on_ready, self._on_core_ready = self._on_core_ready, None
+        if on_ready is not None:
+            on_ready()
 
 
 class _Ready:
@@ -415,9 +467,7 @@ class BatchExecutor:
                         # doubles).
                         _faults.ACTIVE.hit("serve_batch",
                                            lanes=pending.lanes, n=pending.n)
-                    pending.t_dispatch = time.monotonic()
-                    pending.handle = self._dispatch(engine, padded,
-                                                    pending.params)
+                    self._launch(engine, pending)
                     span.set(batch=pending.bid, attempt=pending.attempt)
                     return pending
                 except Exception as exc:  # noqa: BLE001 — gated by the classifier
@@ -454,10 +504,7 @@ class BatchExecutor:
             while True:
                 try:
                     if pending.handle is None:  # re-dispatch after a retry
-                        pending.t_dispatch = time.monotonic()
-                        pending.handle = self._dispatch(
-                            engine, pending.padded, pending.params
-                        )
+                        self._launch(engine, pending)
                     res = self._fetch_watched(engine, pending)
                     # The batch's device occupancy — the per-query GTEPS
                     # denominator. Under pipelining, dispatch time
@@ -526,6 +573,21 @@ class BatchExecutor:
             self.finish_batch(pending)
 
     # --- internals --------------------------------------------------------
+
+    def _launch(self, engine, pending: PendingBatch) -> None:
+        """Dispatch the batch's core and, where the engine has the hook,
+        its answer reductions right behind it (``launch_summaries``), so
+        its answers do not queue behind cores dispatched later. The
+        eccentricity reduction is launched only where _extract will read
+        it: some query skips its distance row."""
+        pending.t_dispatch = time.monotonic()
+        pending.handle = self._dispatch(engine, pending.padded,
+                                        pending.params)
+        launch = getattr(engine, "launch_summaries", None)
+        if launch is not None:
+            launch(pending.handle, ecc=any(
+                not getattr(q, "want_distances", True)
+                for q in pending.queries))
 
     @staticmethod
     def _dispatch(engine, padded, params=None):

@@ -218,7 +218,9 @@ class BfsService:
     ``resume_levels=K`` (dist2d) adds level-checkpointed resume so the
     re-admitted queries continue from their last snapshot instead of
     the source. ``linger_ms`` bounds how long a
-    partial batch waits for fill; ``queue_cap`` bounds the backlog
+    partial batch waits for fill on an idle device; with ``pipeline`` on,
+    a partial batch also waits while a dispatched core is still running
+    (scheduler.py, device pacing); ``queue_cap`` bounds the backlog
     (overload sheds with REJECTED); ``deadline_ms`` (default: none)
     bounds each query's QUEUE wait — see scheduler.py for the semantics.
     An OOM at rung W evicts W and every wider rung and re-admits the
@@ -1905,6 +1907,9 @@ class BfsService:
             if n:
                 self.metrics.record_errors(n)
         finally:
+            # A core whose waiter never reports back (a watchdog trip)
+            # holds no partial batch past its own fetch half.
+            pending.mark_core_ready()
             with self._audit_quiesce:
                 self._finishing -= 1
 
@@ -1958,7 +1963,11 @@ class BfsService:
 
     def _loop(self) -> None:
         while True:
-            batch = self._queue.next_batch(self.lanes, self._linger_s)
+            # Device pacing (scheduler.py): a partial batch waits while a
+            # dispatched core is unfinished (only the pipeline has one).
+            batch = self._queue.next_batch(
+                self.lanes, self._linger_s, on_held=self.metrics.record_hold,
+            )
             if self._queue.stopped:
                 n = 0
                 for q in batch:
@@ -2044,6 +2053,8 @@ class BfsService:
                 continue
             if pending is not None:
                 if self._pipe_q is not None:
+                    self._queue.core_started()
+                    pending.watch_core(self._queue.core_finished)
                     # Bounded handoff: blocks when the extraction worker
                     # falls behind (pipeline_depth batches) — natural
                     # backpressure.

@@ -242,6 +242,10 @@ class ServeMetrics:
         self.padded_lanes_total = 0  # guarded-by: _lock — residual pad waste
         self.batches_by_width = Counter()  # guarded-by: _lock — width -> batches
         self.extract_ms_total = 0.0  # guarded-by: _lock
+        # Batches whose forming waited for the device (a partial batch
+        # held while a dispatched core ran), and the total wait.
+        self.batches_held = 0  # guarded-by: _lock
+        self.held_ms_total = 0.0  # guarded-by: _lock
         # Interval bookkeeping for the statsz line's recent-QPS figure.
         self._last_snap_t = self._t0  # guarded-by: _lock
         self._last_snap_completed = 0  # guarded-by: _lock
@@ -277,6 +281,11 @@ class ServeMetrics:
             self._lat_recent = [Log2Histogram(), self._lat_recent[0]]
             self._ext_recent = [Log2Histogram(), self._ext_recent[0]]
         self._recent_t0 = self._now()
+
+    def record_hold(self, held_ms: float) -> None:
+        with self._lock:
+            self.batches_held += 1
+            self.held_ms_total += held_ms
 
     def record_rejected(self) -> None:
         with self._lock:
@@ -442,6 +451,8 @@ class ServeMetrics:
                 "extract_p50_ms": self._round(ext.percentile(50)),
                 "extract_ms_total": round(self.extract_ms_total, 3),
                 "batches": self.batches,
+                "batches_held": self.batches_held,
+                "held_ms_total": round(self.held_ms_total, 3),
                 "rejected": self.rejected,
                 "expired": self.expired,
                 "errors": self.errors,

@@ -10,6 +10,14 @@ bridging that impedance:
 - COALESCING: each dispatch drains up to ``max_n`` pending queries into
   one batch, lingering up to ``linger_s`` for stragglers when the batch
   is not yet full (latency <-> fill trade, the --linger-ms knob);
+- DEVICE PACING: a partial batch is not dispatched while an earlier
+  batch's core is still running on the device (``core_started`` /
+  ``core_finished``). It would only queue behind that core, so it keeps
+  taking arrivals instead, and goes when it fills or when its linger has
+  passed and the last core in flight has finished. A batch that holds a
+  deadlined query is never held past its linger, so deadline outcomes
+  are those of plain coalescing. Full batches go at once and keep the
+  device fed;
 - DEADLINES: a query whose deadline passes while queued resolves with
   DEADLINE_EXCEEDED at batch-forming time, and ``expired()`` is checked
   AGAIN at dispatch (serve/executor.dispatch_batch) — a query that
@@ -290,15 +298,43 @@ class AdmissionQueue:
         # and so pure single-kind traffic (the common case) keeps the
         # original popleft fast path with no deque rebuild.
         self._key_counts: dict = {}  # guarded-by: _cond
+        # Per-batch-key counts of pending queries that carry a deadline:
+        # a batch of such a key is not held on the device.
+        self._key_dated: dict = {}  # guarded-by: _cond
+        # Dispatched batches whose core has not finished on the device
+        # (core_started / core_finished): what a held batch waits on.
+        self._cores = 0  # guarded-by: _cond
         self._cond = threading.Condition()
         self._stopped = False  # guarded-by: _cond
 
-    def _bump(self, key, d: int) -> None:  # requires-lock: _cond
-        c = self._key_counts.get(key, 0) + d
+    @staticmethod
+    def _add(counts: dict, key, d: int) -> None:
+        c = counts.get(key, 0) + d
         if c:
-            self._key_counts[key] = c
+            counts[key] = c
         else:
-            self._key_counts.pop(key, None)
+            counts.pop(key, None)
+
+    def _bump(self, key, queries, sign: int) -> None:  # requires-lock: _cond
+        """Count ``queries`` (all of batch key ``key``) in (+1) or out
+        (-1) of the per-key tallies."""
+        self._add(self._key_counts, key, sign * len(queries))
+        dated = sum(getattr(q, "deadline", None) is not None
+                    for q in queries)
+        if dated:
+            self._add(self._key_dated, key, sign * dated)
+
+    def core_started(self) -> None:
+        """A dispatched batch's core is now on the device."""
+        with self._cond:
+            self._cores += 1
+
+    def core_finished(self) -> None:
+        """A core counted by :meth:`core_started` finished: a held batch
+        may go."""
+        with self._cond:
+            self._cores -= 1
+            self._cond.notify_all()
 
     def offer(self, q: PendingQuery) -> bool:
         """Admit, or False when the queue is full/stopped (caller sheds)."""
@@ -306,7 +342,7 @@ class AdmissionQueue:
             if self._stopped or len(self._items) >= self.cap:
                 return False
             self._items.append(q)
-            self._bump(self._key_of(q), 1)
+            self._bump(self._key_of(q), (q,), 1)
             depth = len(self._items)
             self._cond.notify()
         rec = _obs.ACTIVE
@@ -323,7 +359,7 @@ class AdmissionQueue:
         with self._cond:
             for q in reversed(queries):
                 self._items.appendleft(q)
-                self._bump(self._key_of(q), 1)
+                self._bump(self._key_of(q), (q,), 1)
             self._cond.notify()
         rec = _obs.ACTIVE
         if rec is not None:
@@ -343,7 +379,8 @@ class AdmissionQueue:
     def _key_of(q) -> tuple:
         return getattr(q, "batch_key", ("bfs",))
 
-    def next_batch(self, max_n: int, linger_s: float) -> list:
+    def next_batch(self, max_n: int, linger_s: float, *,
+                   on_held=None) -> list:
         """Block until work exists, then drain up to ``max_n`` queries
         COMPATIBLE with the head query's batch key (ISSUE 14: only
         same-kind — and same-k for khop — queries can share a device
@@ -351,14 +388,23 @@ class AdmissionQueue:
 
         When fewer than ``max_n`` compatible queries are pending, lingers
         up to ``linger_s`` from the moment the batch starts forming,
-        returning early the instant it fills. After ``stop()`` the
-        remaining queries drain immediately (no linger, no kind filter —
+        returning early the instant it fills. A partial batch also waits
+        while any core counted by :meth:`core_started` is unfinished,
+        taking arrivals: it goes when it fills, or when its linger has
+        passed and no such core is left. A deadlined query of its key
+        ends that wait, and the batch then goes once its linger has
+        passed, as it would with no core in flight. ``on_held(ms)`` is
+        told how long a held batch waited on the device. After ``stop()``
+        the remaining queries drain immediately (no linger, no kind filter —
         the caller only resolves them as SHUTDOWN); returns [] only when
         stopped AND empty.
 
         The forming of a batch, from the moment work exists, is the host
         span ``tpu_bfs.serve.coalesce`` (tpu_bfs/obs): pending queries at
-        its start, queries taken, and milliseconds it lasted."""
+        its start, queries taken, milliseconds it lasted, milliseconds of
+        it spent waiting on the device (``held_ms``) and what released it
+        (``released_by``: ``full``, ``device``, ``deadline`` or
+        ``linger``)."""
         with self._cond:
             while not self._items and not self._stopped:
                 self._cond.wait()
@@ -366,30 +412,52 @@ class AdmissionQueue:
                 taken = []
                 while self._items and len(taken) < max_n:
                     q = self._items.popleft()
-                    self._bump(self._key_of(q), -1)
+                    self._bump(self._key_of(q), (q,), -1)
                     taken.append(q)
                 return taken
             t0 = time.monotonic()
             pending = len(self._items)
+            held_ms = 0.0
+            released_by = "full"
             with _obs.host_span("tpu_bfs.serve.coalesce", lambda: {
                 "pending": pending, "taken": len(taken),
                 "lingered_ms": (time.monotonic() - t0) * 1e3,
+                "held_ms": held_ms, "released_by": released_by,
             }):
                 key = self._key_of(self._items[0])
-                if linger_s > 0:
-                    deadline = t0 + linger_s
-                    while (self._key_counts.get(key, 0) < max_n
-                           and not self._stopped):
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            break
-                        self._cond.wait(remaining)
+                t_held = t_freed = None
+                freed_by = "device"
+                while (self._key_counts.get(key, 0) < max_n
+                       and not self._stopped):
+                    now = time.monotonic()
+                    if self._cores > 0 and key not in self._key_dated:
+                        # A core is ahead of this batch on the device:
+                        # dispatching now would only queue behind it.
+                        if t_held is None:
+                            t_held = now
+                        self._cond.wait()
+                        continue
+                    if t_held is not None and t_freed is None:
+                        t_freed = now
+                        if self._cores > 0:
+                            freed_by = "deadline"
+                    remaining = t0 + linger_s - now
+                    if remaining <= 0:
+                        released_by = (freed_by if t_freed is not None
+                                       and t_freed >= t0 + linger_s
+                                       else "linger")
+                        break
+                    self._cond.wait(remaining)
+                if t_held is not None:
+                    held_ms = ((t_freed or time.monotonic()) - t_held) * 1e3
+                    if on_held is not None:
+                        on_held(held_ms)
                 if len(self._key_counts) == 1:
                     # Single-kind traffic: the original O(batch) popleft
                     # path.
                     n = min(max_n, len(self._items))
                     taken = [self._items.popleft() for _ in range(n)]
-                    self._bump(key, -n)
+                    self._bump(key, taken, -1)
                     return taken
                 taken = []
                 rest: deque = deque()
@@ -399,7 +467,7 @@ class AdmissionQueue:
                     else:
                         rest.append(q)
                 self._items = rest
-                self._bump(key, -len(taken))
+                self._bump(key, taken, -1)
                 return taken
 
     def stop(self) -> None:

@@ -190,3 +190,100 @@ def test_dist_hybrid_w256_lanes_past_4096(random_small):
             )
     with pytest.raises(ValueError):
         DistHybridMsBfsEngine(random_small, make_mesh(4), lanes=6144)
+
+
+def test_cli_mesh4_hybrid_at_8192_lanes_matches_the_reference():
+    """The four-chip deployment's normal path at its width: the CLI's
+    ``--multi-source --devices 4 --engine hybrid --lanes 8192 --planes 5``
+    on a small Kronecker graph, 4 of the suite's virtual devices. Every
+    lane's reached count and the batch's level count against the
+    reference, a seeded sample of distance rows (lanes past word column
+    128 among them), and the same batch bit for bit on the single-chip
+    hybrid at the same width."""
+    from tpu_bfs import cli
+    from tpu_bfs.reference import bfs_scipy
+
+    rng = np.random.default_rng(26)
+    got = {}
+
+    def keep(graph, engine, res):
+        got.update(g=graph, engine=engine, res=res)
+
+    spec = "rmat:scale=9,ef=16,seed=1"
+    g = cli.load_graph(spec)
+    sources = rng.integers(0, g.num_vertices, size=8192)
+    argv = [str(sources[0]), spec, "--multi-source",
+            ",".join(str(int(s)) for s in sources[1:]), "--skip-cpu",
+            "--devices", "4", "--engine", "hybrid", "--lanes", "8192",
+            "--planes", "5"]
+    assert cli.main(argv, on_result=keep) == 0
+    engine, res = got["engine"], got["res"]
+    assert isinstance(engine, DistHybridMsBfsEngine)
+    assert engine.mesh.devices.size == 4 and engine.w == 256
+    assert engine.hd["num_tiles"] > 0  # the MXU tile pass runs
+    golden = {int(s): bfs_scipy(got["g"], int(s)) for s in set(sources)}
+    reached = {s: int(np.count_nonzero(d < g.num_vertices))
+               for s, d in golden.items()}
+    np.testing.assert_array_equal(
+        res.reached, [reached[int(s)] for s in sources])
+    ecc = max(int(d[d < g.num_vertices].max()) for d in golden.values())
+    assert res.num_levels == ecc
+    lanes = sorted({0, 4095, 4096, 8191,
+                    *rng.choice(8192, size=12, replace=False).tolist()})
+    assert any(i >= 4096 for i in lanes)
+    for i in lanes:
+        np.testing.assert_array_equal(
+            res.distances_int32(i), golden[int(sources[i])],
+            err_msg=f"lane {i}")
+    one = HybridMsBfsEngine(got["g"], lanes=8192, num_planes=5).run(sources)
+    assert one.num_levels == res.num_levels
+    np.testing.assert_array_equal(one.reached, res.reached)
+    np.testing.assert_array_equal(one.edges_traversed, res.edges_traversed)
+    for i in lanes:
+        np.testing.assert_array_equal(one.distances_int32(i),
+                                      res.distances_int32(i))
+
+
+@pytest.mark.parametrize("exchange", ["dense", "sparse"])
+def test_fetch_span_exchange_bytes_match_the_engine_accounting(
+        random_small, exchange, tmp_path):
+    """The ``tpu_bfs.engine.fetch`` span's ``exchange_bytes``, computed on
+    the host from static shapes and the batch's level-loop passes, is the
+    engine's own deferred accounting (``last_exchange_bytes``: bytes each
+    chip receives) for the dense exchange; the sparse exchange, whose
+    bytes follow the branch each level took on the device, leaves it out,
+    as does a single-chip engine."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    engines = [DistHybridMsBfsEngine(random_small, make_mesh(4),
+                                     tile_thr=2, exchange=exchange),
+               HybridMsBfsEngine(random_small, tile_thr=2)]
+    sources = np.arange(64) * 7 % random_small.num_vertices
+    for e in engines:
+        e.run(sources)  # compiled outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for e in engines:
+            with jax.profiler.TraceAnnotation(type(e).__name__):
+                e.run(sources)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    spans = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "tpu_bfs.engine.fetch"]
+    assert len(spans) == 2
+    dist, single = sorted(spans, key=lambda st: "exchange_bytes" in st)[::-1]
+    assert "exchange_bytes" not in single
+    if exchange == "sparse":
+        assert "exchange_bytes" not in dist
+        return
+    mesh = engines[0]
+    assert dist["levels"] >= 2
+    assert dist["exchange_bytes"] == mesh.last_exchange_bytes > 0
+    # (P - 1) / P of the whole [rows, w] u32 table a chip, each pass.
+    rows = mesh.hd["rows"]
+    assert dist["exchange_bytes"] == dist["levels"] * 3 * rows // 4 * 4 * 128

@@ -3,7 +3,8 @@ level loops and ``obs.host_span`` on the host.
 
 - coverage: every op a compiled core's own code made (its ``op_name``
   metadata under ``jit(core)/``) names a ``level.*`` / ``batch.*`` phase
-  and the ``lanes<W>`` of its engine's width;
+  and the ``lanes<W>`` of its engine's width, the 1D-sharded engine's
+  exchange and termination sum among them;
 - identity: the optimized HLO, metadata and stack-frame tables stripped,
   is the same with ``obs.scope`` replaced by a null context: the names
   change nothing the device runs;
@@ -33,9 +34,17 @@ from tpu_bfs.algorithms.msbfs_hybrid import HybridMsBfsEngine
 from tpu_bfs.algorithms.msbfs_packed import PackedMsBfsEngine
 from tpu_bfs.algorithms.msbfs_wide import WidePackedMsBfsEngine
 from tpu_bfs.graph.generate import random_graph, rmat_graph
+from tpu_bfs.parallel.dist_bfs import make_mesh
+from tpu_bfs.parallel.dist_msbfs_hybrid import DistHybridMsBfsEngine
 
 #: Instructions that run nothing themselves.
 NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+#: The sharded core's one op with no phase: the distance planes' zero
+#: fill, which XLA lifts out of the ``shard_map`` body under the name of
+#: its own instruction (``broadcast.421``). Filled outside the body it
+#: loses even the ``jit(core)`` prefix; JAX names every op it emits by its
+#: primitive, with no such suffix.
+PLANE_FILL = re.compile(r"^jit\(core\)/shard_map/broadcast\.\d+$")
 
 ENGINES = {
     "packed64": lambda: PackedMsBfsEngine(rmat_graph(10, 8, seed=3),
@@ -47,6 +56,11 @@ ENGINES = {
                                              lanes=256, kcap=8),
     "hybrid_tiles": lambda: HybridMsBfsEngine(rmat_graph(10, 8, seed=3),
                                               tile_thr=8, kcap=4),
+    # The 1D-sharded hybrid at the four-chip cell's width, on 4 of the
+    # suite's virtual devices.
+    "mesh4_hybrid8192": lambda: DistHybridMsBfsEngine(
+        rmat_graph(10, 8, seed=3), make_mesh(4), lanes=8192, tile_thr=8,
+        kcap=4),
 }
 
 
@@ -73,6 +87,9 @@ def _core_hlo(engine) -> str:
         fw0 = jnp.asarray(engine._seed(sources))
         lowered = engine._core.lower(engine.arrs, fw0, fw0[:-1],
                                      jnp.int32(8))
+    elif isinstance(engine, DistHybridMsBfsEngine):
+        fw0 = engine._seed_dev(sources)
+        lowered = engine._dist_core.lower(engine.arrs, fw0, jnp.int32(8))
     else:
         fw0 = engine._seed_dev(sources)
         lowered = engine._core.lower(engine.arrs, fw0, jnp.int32(8))
@@ -120,9 +137,13 @@ def test_every_core_op_names_its_phase(name):
     engine = ENGINES[name]()
     scopes = set()
     unnamed = []
+    fills = set()
     for opcode, op_name in _running_instructions(_core_hlo(engine)):
         if opcode in NO_WORK or op_name is None:
             continue  # XLA's own copies and constant fills carry no name
+        if name == "mesh4_hybrid8192" and PLANE_FILL.match(op_name):
+            fills.add(op_name)
+            continue
         if not op_name.startswith("jit(core)/"):
             continue  # argument names
         parts = op_name.split("/")
@@ -132,9 +153,12 @@ def test_every_core_op_names_its_phase(name):
         else:
             scopes.add(phase[-1])
     assert unnamed == []
+    assert len(fills) <= 1
     assert {"level.gather", "level.claim"} <= scopes
-    if name == "hybrid_tiles":
+    if name in ("hybrid_tiles", "mesh4_hybrid8192"):
         assert "level.tiles" in scopes
+    if name == "mesh4_hybrid8192":
+        assert {"level.exchange", "level.sync"} <= scopes
     if name in ("packed64", "wide256"):
         assert "level.heavy" in scopes
 

@@ -1971,6 +1971,21 @@ def dispatch_packed_batch(
     )
 
 
+def _fetch_stats(engine, levels) -> dict:
+    """The fetch span's stats: the batch's width and level-loop passes,
+    and on a mesh engine whose exchange has one branch (dense or sliced)
+    the bytes each chip received in the batch's frontier exchanges, its
+    passes times the modeled bytes a pass: static shapes, no device
+    read. A sparse exchange's bytes follow the branch each level took
+    on the device (``last_exchange_bytes``)."""
+    stats = {"lanes": engine.lanes, "levels": levels}
+    wire = getattr(engine, "wire_bytes_per_level", None)
+    per = None if wire is None or levels is None else wire()
+    if per is not None and len(per) == 1:
+        stats["exchange_bytes"] = levels * per[0]
+    return stats
+
+
 def fetch_packed_batch(
     engine, pend: PackedDispatch, *, check_cap: bool = True,
     time_it: bool = False,
@@ -1986,9 +2001,8 @@ def fetch_packed_batch(
             devices=_faults.mesh_devices(engine),
         )
     levels = None
-    with _obs.host_span("tpu_bfs.engine.fetch", lambda: {
-        "lanes": engine.lanes, "levels": levels,
-    }):
+    with _obs.host_span("tpu_bfs.engine.fetch",
+                        lambda: _fetch_stats(engine, levels)):
         levels = int(pend.levels)  # blocks until the loop finishes
         elapsed = (time.perf_counter() - pend.t0) if time_it else None
         engine._warmed = True

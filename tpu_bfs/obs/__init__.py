@@ -42,7 +42,9 @@ ops land in:
   (tests/test_obs_scopes.py holds the compiled programs equal with and
   without it). The level loop's phases are
   ``level.tiles``, ``level.gather``, ``level.heavy``, ``level.push`` and
-  ``level.claim``; a batch's set-up and reductions ``batch.seed`` and
+  ``level.claim``, and on a mesh ``level.exchange`` (the frontier's
+  exchange between chips) and ``level.sync`` (the termination sum over
+  chips); a batch's set-up and reductions ``batch.seed`` and
   ``batch.stats``; an engine's whole core ``lanes<W>``.
 - :func:`host_span` names one host step per batch (``tpu_bfs.engine.*``,
   ``tpu_bfs.serve.*``), or one set-up step (``tpu_bfs.setup.*``,

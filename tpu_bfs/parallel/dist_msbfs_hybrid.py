@@ -61,6 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
+from tpu_bfs import obs as _obs
 from tpu_bfs.graph.csr import Graph
 from tpu_bfs.graph.ell import (
     _ell_fill,
@@ -515,7 +516,8 @@ def _make_dist_core(
     )
 
     def _global_any(x):
-        return lax.psum(jnp.any(x != 0).astype(jnp.int32), "v") > 0
+        with _obs.scope("level.sync"):
+            return lax.psum(jnp.any(x != 0).astype(jnp.int32), "v") > 0
 
     def _make_loop_sliced(arrs, max_levels, lane_mask=None):
         """Ring-sliced level machinery: no gathered frontier ever exists.
@@ -540,12 +542,17 @@ def _make_dist_core(
         ring = [(i, (i + 1) % p_count) for i in range(p_count)]
 
         def contrib(fw, fw_ext, s_arrs):
-            out = expand({k: s_arrs[k] for k in res_keys}, fw_ext)[s_arrs["perm"]]
+            with _obs.scope("level.gather"):
+                out = expand(
+                    {k: s_arrs[k] for k in res_keys}, fw_ext
+                )[s_arrs["perm"]]
             if has_dense:
-                out = out | tile_spmm(
-                    s_arrs["row_start"], s_arrs["col_tile"], s_arrs["a_tiles"],
-                    fw, num_row_tiles=nrt, w=w, interpret=interpret,
-                )
+                with _obs.scope("level.tiles"):
+                    out = out | tile_spmm(
+                        s_arrs["row_start"], s_arrs["col_tile"],
+                        s_arrs["a_tiles"], fw, num_row_tiles=nrt, w=w,
+                        interpret=interpret,
+                    )
             return out
 
         def hit_claim(fw, vis):
@@ -571,7 +578,8 @@ def _make_dist_core(
             acc = step({k: arrs[k][0] for k in step_keys})
 
             def sbody(acc, xs):
-                acc = lax.ppermute(acc, "v", ring)
+                with _obs.scope("level.exchange"):
+                    acc = lax.ppermute(acc, "v", ring)
                 return acc | step(xs), None
 
             if p_count > 1:
@@ -602,19 +610,21 @@ def _make_dist_core(
         def body(carry):
             fw, vis, planes, level, _, bc = carry[:6]
             hit, branch, skipped = body_claim(fw, vis)
-            nxt = hit & ~vis
-            vis2 = vis | nxt
-            planes = ripple_increment(planes, ~vis2)
-            bc = bc + (jnp.arange(nb, dtype=jnp.int32) == branch)
+            with _obs.scope("level.claim"):
+                nxt = hit & ~vis
+                vis2 = vis | nxt
+                planes = ripple_increment(planes, ~vis2)
             # One psum per level is the whole termination protocol (the
             # reference needs a host-visible MPI_Allreduce, bfs_mpi.cu:621).
             alive = _global_any(nxt)
-            out = (nxt, vis2, planes, level + 1, alive, bc)
-            if gated:
-                gc = carry[6].at[
-                    jnp.minimum(level, gate_levels - 1)
-                ].set(skipped)
-                out = out + (gc,)
+            with _obs.scope("level.sync"):
+                bc = bc + (jnp.arange(nb, dtype=jnp.int32) == branch)
+                out = (nxt, vis2, planes, level + 1, alive, bc)
+                if gated:
+                    gc = carry[6].at[
+                        jnp.minimum(level, gate_levels - 1)
+                    ].set(skipped)
+                    out = out + (gc,)
             return out
 
         def run_from(fw, vis, planes, level0):
@@ -622,7 +632,10 @@ def _make_dist_core(
                     jnp.zeros(nb, jnp.int32))
             if gated:
                 init = init + (jnp.zeros(gate_levels, jnp.int32),)
-            return lax.while_loop(cond, body, init)
+            # The loop itself (its termination test and carry) is sync
+            # work; each phase of the body names itself inside it.
+            with _obs.scope("level.sync"):
+                return lax.while_loop(cond, body, init)
 
         return run_from
 
@@ -660,33 +673,38 @@ def _make_dist_core(
             )
 
         def gather_frontier(fw_own):
-            if exchange == "sparse":
-                return sparse_gather(fw_own)
-            return dense_gather(fw_own), jnp.int32(0)
+            with _obs.scope("level.exchange"):
+                if exchange == "sparse":
+                    return sparse_gather(fw_own)
+                return dense_gather(fw_own), jnp.int32(0)
 
         def hit_of_gathered(fw_g, vis):
-            if gated:
-                # Destination-settled gating, chip-resident: this chip's
-                # vis shard covers exactly the rows its buckets produce.
-                valid_rows = arrs["valid"][:, 0] != 0
-                need = (
-                    jnp.any((~vis & lane_mask[None, :]) != 0, axis=1)
-                    & valid_rows
-                )
-                need_ext = jnp.concatenate([need, jnp.zeros((1,), bool)])
-                res, skipped = gated_expand(
-                    arrs, fw_g, need_ext[arrs["gate_fwd"]]
-                )
-                hit = res[arrs["perm"]]
-            else:
-                hit = expand(arrs, fw_g)[arrs["perm"]]  # own rows, local
-                skipped = jnp.int32(0)
+            with _obs.scope("level.gather"):
+                if gated:
+                    # Destination-settled gating, chip-resident: this
+                    # chip's vis shard covers exactly the rows its buckets
+                    # produce.
+                    valid_rows = arrs["valid"][:, 0] != 0
+                    need = (
+                        jnp.any((~vis & lane_mask[None, :]) != 0, axis=1)
+                        & valid_rows
+                    )
+                    need_ext = jnp.concatenate([need, jnp.zeros((1,), bool)])
+                    res, skipped = gated_expand(
+                        arrs, fw_g, need_ext[arrs["gate_fwd"]]
+                    )
+                    hit = res[arrs["perm"]]
+                else:
+                    hit = expand(arrs, fw_g)[arrs["perm"]]  # own rows, local
+                    skipped = jnp.int32(0)
             if has_dense:
-                hit = hit | tile_spmm(
-                    arrs["row_start"], arrs["col_tile"], arrs["a_tiles"], fw_g,
-                    num_row_tiles=nrt, w=w, interpret=interpret,
-                )
-            return hit & arrs["valid"], skipped
+                with _obs.scope("level.tiles"):
+                    hit = hit | tile_spmm(
+                        arrs["row_start"], arrs["col_tile"], arrs["a_tiles"],
+                        fw_g, num_row_tiles=nrt, w=w, interpret=interpret,
+                    )
+            with _obs.scope("level.claim"):
+                return hit & arrs["valid"], skipped
 
         def hit_claim(fw_own, vis):
             return hit_of_gathered(gather_frontier(fw_own)[0], vis)
@@ -698,41 +716,55 @@ def _make_dist_core(
 
         return _make_run_from(body_claim, max_levels), hit_claim
 
-    def chip_fn(arrs, fw0, max_levels, *mask):
-        arrs = {k: a[0] for k, a in arrs.items()}  # strip this chip's P axis
-        run_from, hit_claim = _make_loop(arrs, max_levels, *mask)
-        planes0 = tuple(
-            jnp.zeros((rows_loc, w), jnp.uint32) for _ in range(num_planes)
-        )
-        out = run_from(fw0, fw0, planes0, jnp.int32(0))
-        fw_f, vis_f, planes_f, levels, alive, branch_counts = out[:6]
+    # The per-chip bodies are named ``core`` and ``core_from``, as the
+    # single-chip engines' jitted cores are: their ops read
+    # ``jit(core)/shard_map/lanes<W>/level.*/...`` in a trace.
+    lanes_scope = f"lanes{32 * w}"
 
-        def deeper():
-            return _global_any(hit_claim(fw_f, vis_f)[0] & ~vis_f)
+    def core(arrs, fw0, max_levels, *mask):
+        with _obs.scope(lanes_scope):
+            arrs = {k: a[0] for k, a in arrs.items()}  # this chip's part
+            run_from, hit_claim = _make_loop(arrs, max_levels, *mask)
+            with _obs.scope("batch.seed"):
+                planes0 = tuple(
+                    jnp.zeros((rows_loc, w), jnp.uint32)
+                    for _ in range(num_planes)
+                )
+            out = run_from(fw0, fw0, planes0, jnp.int32(0))
+            fw_f, vis_f, planes_f, levels, alive, branch_counts = out[:6]
 
-        truncated = lax.cond(
-            alive & (levels >= max_levels), deeper, lambda: jnp.bool_(False)
-        )
-        res = (planes_f, vis_f, levels, alive, truncated, branch_counts)
-        if gated:
-            res = res + (out[6][None],)  # [1, L]; host sums the chip axis
-        return res
+            def deeper():
+                hit = hit_claim(fw_f, vis_f)[0]
+                with _obs.scope("level.claim"):
+                    nxt = hit & ~vis_f
+                return _global_any(nxt)
 
-    def chip_fn_from(arrs, fw, vis, planes, level0, max_levels, *mask):
+            with _obs.scope("level.sync"):
+                truncated = lax.cond(
+                    alive & (levels >= max_levels), deeper,
+                    lambda: jnp.bool_(False),
+                )
+            res = (planes_f, vis_f, levels, alive, truncated, branch_counts)
+            if gated:
+                res = res + (out[6][None],)  # [1, L]; host sums the chips
+            return res
+
+    def core_from(arrs, fw, vis, planes, level0, max_levels, *mask):
         # Checkpoint-resume entry: the while-loop carry (all in the same
         # sharded tau row space) restored mid-traversal — bit-identical to
         # never having stopped (_packed_common.advance_packed_batch).
-        arrs = {k: a[0] for k, a in arrs.items()}
-        run_from, _ = _make_loop(arrs, max_levels, *mask)
-        out = run_from(fw, vis, planes, level0)
-        return out[:6] + ((out[6][None],) if gated else ())
+        with _obs.scope(lanes_scope):
+            arrs = {k: a[0] for k, a in arrs.items()}
+            run_from, _ = _make_loop(arrs, max_levels, *mask)
+            out = run_from(fw, vis, planes, level0)
+            return out[:6] + ((out[6][None],) if gated else ())
 
     def build(n_arrs):
         mask_in = (P(),) if gated else ()  # replicated lane mask
         gc_out = (P("v"),) if gated else ()  # [P, L] per-chip counters
-        core = jax.jit(
+        core_jit = jax.jit(
             shard_map(
-                chip_fn,
+                core,
                 mesh=mesh,
                 in_specs=({k: P("v") for k in n_arrs}, P("v"), P())
                 + mask_in,
@@ -748,9 +780,9 @@ def _make_dist_core(
                 check_vma=False,
             )
         )
-        core_from = jax.jit(
+        core_from_jit = jax.jit(
             shard_map(
-                chip_fn_from,
+                core_from,
                 mesh=mesh,
                 in_specs=(
                     {k: P("v") for k in n_arrs},
@@ -777,7 +809,7 @@ def _make_dist_core(
             k: jax.device_put(a, NamedSharding(mesh, P("v")))
             for k, a in n_arrs.items()
         }
-        return core, core_from, device_arrs
+        return core_jit, core_from_jit, device_arrs
 
     return build
 
